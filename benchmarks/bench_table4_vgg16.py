@@ -21,6 +21,7 @@
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import os
@@ -588,100 +589,86 @@ def run_serving_queue(*, img: int = 32, scale: int = 16, batch: int = 8,
     }]
 
 
-# cold-start subprocess body: argv[1] is "cold" (plain program.json — trace
-# + compile on first use) or "warm" (AOT bundle — deserialize the saved
-# executables), argv[2] the saved path. Each runs under a FRESH interpreter
-# so the measurement is an honest process cold start, not a warm-cache replay.
-_AOT_COLD_START_SUBPROC = r"""
-import json, sys, time
-import numpy as np
-from repro import api
-from repro.core.program_cache import ProgramCache
-
-mode, path = sys.argv[1], sys.argv[2]
-img, batch, n_req = 32, 8, 32
-doc_path = path + "/program.json" if mode == "cold" else path
-with open(path + "/program.json") as f:
-    doc = json.load(f)
-specs = [api._spec_from_dict(d) for d in doc["specs"]]
-params = api.random_params(specs, seed=0)
-
-t0 = time.monotonic()
-acc = api.Accelerator.from_program(doc_path, params=params,
-                                   cache=ProgramCache())
-rng = np.random.default_rng(0)
-reqs = [rng.standard_normal((img, img, 3)).astype(np.float32)
-        for _ in range(n_req)]
-with acc.serve(max_batch=batch, buckets=(batch,), warmup=True) as s:
-    outs = s.run_many(reqs)
-    ready_ms = (time.monotonic() - t0) * 1e3
-    st = s.stats
-print("AOT_ROW:" + json.dumps({
-    "compile_ms": st.compile_ms, "warm_load_ms": st.warm_load_ms,
-    "ready_ms": ready_ms,
-    "outs": [np.asarray(y).tolist() for y in outs]}))
-"""
+@contextlib.contextmanager
+def _without_persistent_cache():
+    """Compile for real inside the block: JAX's persistent compilation
+    cache (which ``benchmarks.run`` turns on) would otherwise turn the
+    cold side into a cache read."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        cc.reset_cache()
 
 
 def run_aot_cold_start(*, img: int = 32, scale: int = 16,
-                       batch: int = 8) -> list[dict]:
-    """AOT cold-start row: a fresh process loading the serialized-executable
-    bundle (``save_program(..., aot=True)``) vs a fresh process compiling
-    the same program from its ``program.json`` — the autoscaling-event
-    number the artifact layer exists for.
+                       batch: int = 8, n_req: int = 32) -> list[dict]:
+    """AOT cold-start row: a session loading the serialized-executable
+    bundle (``save_program(..., aot=True)``) vs a session compiling the same
+    program from its ``program.json`` — the autoscaling-event number the
+    artifact layer exists for.
 
-    The parent builds the serving row's reduced VGG16 and saves both forms;
-    each side then runs under its own interpreter (the only honest cold
-    start — in-process "cold" timings inherit warm XLA/jax state). The row
-    records the cold process's ``compile_ms``, the warm process's
+    Both sides run in this process, which holds the device: each starts
+    from ``jax.clear_caches()`` and a fresh ``ProgramCache``, and the cold
+    side compiles with the persistent compilation cache off. The row
+    records the cold side's ``compile_ms``, the warm side's
     ``warm_load_ms`` (its ``compile_ms`` must be 0 — enforced here), their
     ratio (gated lower-is-better by ``tools/bench_compare.py``; the issue
-    targets <= 0.10), end-to-end process-ready wall clocks, and the max
-    |diff| between the two processes' outputs — bitwise 0.0 by construction,
-    since a deserialized executable IS the compiled program.
+    targets <= 0.10), session-ready wall clocks, and the max |diff|
+    between the two sides' outputs — bitwise 0.0 by construction, since a
+    deserialized executable IS the compiled program.
     """
-    import subprocess
-    import sys
     import tempfile
 
+    import jax
     import numpy as np
 
     from repro import api
+    from repro.core.program_cache import ProgramCache
 
     specs = network_specs(img=img, scale=scale, n_classes=10)
     plans = _alternating_plans(specs)
     acc = api.Accelerator.build(specs, plans=plans, seed=0, batch=batch)
+    rng = np.random.default_rng(0)
+    reqs = [rng.standard_normal((img, img, 3)).astype(np.float32)
+            for _ in range(n_req)]
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(repo, "src")
-
-    def _run(mode, path):
-        r = subprocess.run(
-            [sys.executable, "-c", _AOT_COLD_START_SUBPROC, mode, path],
-            capture_output=True, text=True, env=env, timeout=900)
-        if r.returncode != 0:
-            raise RuntimeError(f"aot_cold_start {mode} subprocess failed:\n"
-                               f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}")
-        line = next(l for l in r.stdout.splitlines()
-                    if l.startswith("AOT_ROW:"))
-        return json.loads(line[len("AOT_ROW:"):])
+    def _serve(path):
+        jax.clear_caches()
+        t0 = time.monotonic()
+        acc2 = api.Accelerator.from_program(path, params=acc.params,
+                                            cache=ProgramCache())
+        with acc2.serve(max_batch=batch, buckets=(batch,),
+                        warmup=True) as s:
+            outs = [np.asarray(y) for y in s.run_many(reqs)]
+            ready_ms = (time.monotonic() - t0) * 1e3
+            st = s.stats
+        return {"compile_ms": st.compile_ms,
+                "warm_load_ms": st.warm_load_ms, "ready_ms": ready_ms,
+                "outs": outs}
 
     with tempfile.TemporaryDirectory() as tmp:
         bundle = os.path.join(tmp, "bundle")
         acc.save_program(bundle, aot=True, buckets=(batch,))
-        cold = _run("cold", bundle)
-        warm = _run("warm", bundle)
+        with _without_persistent_cache():
+            cold = _serve(os.path.join(bundle, "program.json"))
+        warm = _serve(bundle)
 
     if warm["compile_ms"] != 0.0:
-        raise RuntimeError(f"warm process compiled "
+        raise RuntimeError(f"warm side compiled "
                            f"({warm['compile_ms']:.1f}ms != 0) — the AOT "
                            f"bundle was not used")
-    diff = max(float(np.abs(np.asarray(a) - np.asarray(b)).max())
+    diff = max(float(np.abs(a - b).max())
                for a, b in zip(cold["outs"], warm["outs"]))
     return [{
         "bench": "table4_vgg16", "name": "serving/aot_cold_start",
         "config": f"img{img}_scale{scale}_batch{batch}",
+        "platform": jax.devices()[0].platform,
         "cold_compile_ms": round(cold["compile_ms"], 1),
         "warm_load_ms": round(warm["warm_load_ms"], 1),
         "warm_over_cold_compile_ratio": round(
@@ -692,9 +679,9 @@ def run_aot_cold_start(*, img: int = 32, scale: int = 16,
     }]
 
 
-# self-contained subprocess body for the fleet row: the parent bench process
-# has already initialized jax with ONE device, so the 4-device measurement
-# must run under a fresh interpreter with the forced host-device count
+# self-contained subprocess body for the fleet row: four forced CPU host
+# devices need a fresh interpreter, and that child runs on the CPU only
+# (JAX_PLATFORMS=cpu), so it never competes for a chip the parent holds
 _FLEET_SHARDED_SUBPROC = r"""
 import json, os, time
 import numpy as np
@@ -802,8 +789,9 @@ def run_fleet_sharded(*, n_devices: int = 4) -> list[dict]:
     scheduler comparison on a bursty arrival trace and the Pallas-under-
     sharding parity evidence.
 
-    Runs in a subprocess (the parent process already pinned jax to one
-    device) with ``--xla_force_host_platform_device_count``. On a
+    Runs in a subprocess on the CPU platform (``JAX_PLATFORMS=cpu``) with
+    ``--xla_force_host_platform_device_count``: a CPU row wherever the
+    benchmark runs, never a second process reaching for the chip. On a
     single-core host the 4-device row CANNOT show real scaling — four
     shard computations time-slice one core — so the row records
     ``host_cores`` alongside ``rps_scaling`` and the regression guard
@@ -818,6 +806,7 @@ def run_fleet_sharded(*, n_devices: int = 4) -> list[dict]:
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_devices}")
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(repo, "src")
     r = subprocess.run([sys.executable, "-c", _FLEET_SHARDED_SUBPROC],
                        capture_output=True, text=True, env=env, timeout=900)
@@ -827,7 +816,8 @@ def run_fleet_sharded(*, n_devices: int = 4) -> list[dict]:
     line = next(l for l in r.stdout.splitlines()
                 if l.startswith("FLEET_ROW:"))
     row = json.loads(line[len("FLEET_ROW:"):])
-    row = {"bench": "table4_vgg16", "name": "serving/fleet_sharded", **row}
+    row = {"bench": "table4_vgg16", "name": "serving/fleet_sharded",
+           "platform": "cpu", **row}
     return [row]
 
 

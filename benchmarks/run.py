@@ -16,6 +16,7 @@ from benchmarks import (
     bench_table3_resources,
     bench_table4_vgg16,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCHES = {
     "table3": bench_table3_resources.run,
@@ -31,6 +32,7 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, choices=sorted(BENCHES))
     args = ap.parse_args()
+    enable_compile_cache()
     names = [args.only] if args.only else list(BENCHES)
     failed = False
     for name in names:

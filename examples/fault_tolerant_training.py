@@ -55,7 +55,7 @@ def main():
         print(f"restarts: {log['restarts']} (recovered and finished 20 steps)")
 
         print("\n== elastic re-mesh: restore onto a different mesh ==")
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((1,), ("model",))
         # a different (here trivial) mesh: every leaf re-placed by device_put
         restored, step = ckpt_lib.restore(ckpt_dir, (params, opt_state))

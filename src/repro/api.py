@@ -36,8 +36,8 @@ tenancy.
 
 ``backend="xla" | "pallas"`` (on ``build``, ``from_program``, and inherited
 by sessions) selects the PE implementation every CONV/FC block lowers
-through — the XLA ops (the default) or the Pallas PE kernels
-(interpret-mode fallback off-TPU). See ``docs/ARCHITECTURE.md`` for
+through — the XLA ops (the default) or the Pallas PE kernels (compiled on
+a TPU, interpret mode on the CPU). See ``docs/ARCHITECTURE.md`` for
 the plug-in table and ``docs/API.md`` for the full reference.
 """
 from __future__ import annotations
@@ -310,7 +310,8 @@ class Accelerator:
     (reuse a saved instruction stream, skipping the DSE). ``backend``
     selects the PE implementation the executor lowers each CONV/FC block
     through — ``"xla"`` (default) or ``"pallas"`` (the Pallas TPU kernels,
-    interpret-mode on CPU unless overridden) — see ``docs/ARCHITECTURE.md``.
+    compiled on a TPU, interpret mode on the CPU unless overridden) — see
+    ``docs/ARCHITECTURE.md``.
 
     Instances are callable: ``acc(x)`` runs one inference request through
     the cached executor. :meth:`summary` prints the per-layer DSE verdict,
@@ -364,9 +365,9 @@ class Accelerator:
 
         ``backend="pallas"`` routes every CONV/FC block through the Pallas
         PE kernels instead of the XLA ops; ``interpret`` overrides the
-        Pallas interpret-mode auto-selection (``None`` = interpret mode
-        everywhere but real TPU). ``opt_level`` selects the lowering
-        optimizer — ``1`` (default) collapses each layer's per-block loop
+        Pallas interpret-mode resolution (``None`` = from the device the
+        executor runs on: compiled on a TPU, interpret mode elsewhere).
+        ``opt_level`` selects the lowering optimizer — ``1`` (default) collapses each layer's per-block loop
         into one whole-layer PE dispatch where provably equivalent, ``0``
         keeps the literal per-block lowering (the reference). Backend and
         opt_level both join the program-cache key, so the same Program
@@ -1184,6 +1185,8 @@ class ServingSession:
                 self._params_sharded = jax.device_put(
                     self._params,
                     jax.NamedSharding(mesh, jax.sharding.PartitionSpec()))
+                self._x_sharding = jax.NamedSharding(
+                    mesh, jax.sharding.PartitionSpec(tuple(mesh.axis_names)))
                 self._fleet_device_ids = tuple(
                     int(d.id) for d in mesh.devices.flat)
                 self._local_device_ids = (self._fleet_device_ids[0],)
@@ -1679,13 +1682,17 @@ class ServingSession:
         return y_np
 
     def _run_bucket(self, x):
+        """Place one staged batch on its device(s) and run it. A bucket
+        with a sharded entry goes straight onto the mesh, split over the
+        batch axis, so no shard is staged through the first device."""
         b = x.shape[0]
         entry = self._sharded_entries.get(b)
         if entry is not None:
-            return entry(self._params_sharded, x)
+            return entry(self._params_sharded,
+                         jax.device_put(x, self._x_sharding))
         entry = self._entries.get(b)
         if entry is not None:
-            return entry(self._params, x)
+            return entry(self._params, jnp.asarray(x))
         return self.acc(x)
 
     def _stage_group(self, group, n, *, bulk: bool = False):
@@ -1746,14 +1753,14 @@ class ServingSession:
         first_use = bucket not in self._warm
         t0 = time.monotonic()
         # the staging ring guarantees this buffer is not refilled until its
-        # slot drains, so jnp.asarray may copy OR zero-copy-alias it safely
+        # slot drains, so placing it may copy OR zero-copy-alias it safely
         if first_use:
             with _expected_donation_noise():   # compile happens in this call
-                y = self._run_bucket(jnp.asarray(buf))
+                y = self._run_bucket(buf)
             self._count_first_use(bucket, t0)
             self._warm.add(bucket)
         else:
-            y = self._run_bucket(jnp.asarray(buf))
+            y = self._run_bucket(buf)
         return y
 
     # -- failure handling ---------------------------------------------------
@@ -1866,7 +1873,7 @@ class ServingSession:
             entry, params = self._fallback_entry(bucket)
             y = entry(params, jnp.asarray(buf))
         else:
-            y = self._run_bucket(jnp.asarray(buf))
+            y = self._run_bucket(buf)
         return self._to_host(y)
 
     def _recover(self, group, bucket, buf, exc):

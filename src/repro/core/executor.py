@@ -42,9 +42,10 @@ implementations, selected by ``backend=``:
   the lowered function can live inside a pjit-sharded model.
 * ``"pallas"`` — the Pallas PE kernels (``kernels/spatial_conv`` for
   Spatial CONV, ``kernels/winograd`` + ``kernels/gemm`` for Winograd CONV,
-  ``kernels/gemm`` for FC). ``interpret=None`` auto-selects interpret mode
-  off-TPU (``kernels.common.INTERPRET``) so the same Program runs on the
-  CPU test container; pass ``interpret=False`` to force compiled lowering.
+  ``kernels/gemm`` for FC). ``interpret=None`` resolves from the device the
+  executor runs on (``kernels.common.interpret_default``): compiled kernels
+  on a TPU, interpret mode elsewhere, so the same Program runs in the CPU
+  test suite; pass ``interpret=False`` to force compiled lowering.
 
 Both backends lower the identical blocked schedule — only the per-block PE
 changes — and are asserted equal (to tolerance) over full reduced VGG16 in
@@ -120,17 +121,19 @@ def resolve_opt_level(opt_level: int) -> int:
     return int(opt_level)
 
 
-def resolve_backend(backend: str, interpret: bool | None
+def resolve_backend(backend: str, interpret: bool | None, mesh=None
                     ) -> tuple[str, bool | None]:
     """Normalize a ``(backend, interpret)`` pair to its effective value.
 
     ``interpret`` only means something on the Pallas backend; ``None`` there
-    resolves to ``kernels.common.INTERPRET`` (interpret mode everywhere but
-    real TPU). Passing a non-None ``interpret`` with ``backend="xla"`` is a
-    contradiction — the XLA lowering would silently ignore it and the
+    resolves from the device the executor runs on — the first device of
+    ``mesh``, else JAX's default device: compiled kernels on a TPU, interpret
+    mode on any other platform. Passing a non-None ``interpret`` with
+    ``backend="xla"`` is a contradiction — the XLA lowering would silently ignore it and the
     caller would believe the Pallas interpret path was exercised — so it
     raises instead. The resolved pair is what joins the program-cache key,
-    so an auto-selected fallback and an explicit one share a cache entry.
+    so a resolved ``None`` and the equivalent explicit value share a cache
+    entry.
     """
     if backend not in BACKENDS:
         raise ValueError(
@@ -142,8 +145,9 @@ def resolve_backend(backend: str, interpret: bool | None
                 f"— pass backend='pallas' or drop interpret")
         return "xla", None
     if interpret is None:
-        from repro.kernels.common import INTERPRET
-        return "pallas", INTERPRET
+        from repro.kernels.common import interpret_default
+        device = None if mesh is None else mesh.devices.flat[0]
+        return "pallas", interpret_default(device)
     return "pallas", bool(interpret)
 
 
@@ -786,8 +790,9 @@ def lower_program(program: Program, *, backend: str = "xla",
 
     ``backend`` selects the per-block PE ("xla" or "pallas", see the module
     docstring); ``interpret`` is the Pallas interpret-mode override
-    (``None`` = auto off-TPU). ``opt_level=1`` (default) runs the lowering
-    optimizer (:func:`analyze_program`) and emits the fused / stacked forms
+    (``None`` = resolved from the default device). ``opt_level=1``
+    (default) runs the lowering optimizer (:func:`analyze_program`) and
+    emits the fused / stacked forms
     for layers where they are provably equivalent; ``opt_level=0`` keeps
     the literal per-block lowering everywhere.
 
@@ -957,7 +962,7 @@ def compile_executor(program: Program,
     must not, since callers commonly re-invoke with the same input).
 
     ``mesh`` builds the **sharded executor variant**: the lowered function
-    is wrapped in ``shard_map`` (via ``repro.compat``) over the batch axis,
+    is wrapped in ``jax.shard_map`` over the batch axis,
     split across every mesh axis — params replicated, ``x``/``y`` sharded
     on dim 0. Each device runs the *whole per-shard program locally*, so
     the Pallas PE kernels work under sharding (GSPMD cannot partition an
@@ -968,18 +973,17 @@ def compile_executor(program: Program,
     """
     if stats is None:
         stats = validate_schedule(program)
-    backend, interpret = resolve_backend(backend, interpret)
+    backend, interpret = resolve_backend(backend, interpret, mesh)
     opt_level = resolve_opt_level(opt_level)
     execute = lower_program(program, backend=backend, interpret=interpret,
                             opt_level=opt_level, quant=quant)
     if mesh is not None and mesh_device_count(mesh) > 1:
         from jax.sharding import PartitionSpec
 
-        from repro.compat import shard_map
         batch_spec = PartitionSpec(tuple(mesh.axis_names))
         # check_vma=False: pallas_call outputs carry no varying-manual-axes
         # annotation, and the xla lowering needs no replication check either
-        execute = shard_map(execute, mesh=mesh,
+        execute = jax.shard_map(execute, mesh=mesh,
                             in_specs=(PartitionSpec(), batch_spec),
                             out_specs=batch_spec, check_vma=False)
     trace_count = [0]

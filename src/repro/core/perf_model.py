@@ -100,8 +100,14 @@ PYNQ_Z1 = FPGATarget(
 
 @dataclasses.dataclass(frozen=True)
 class TPUTarget:
-    """TPU v5e chip constants (the dry-run/roofline hardware)."""
+    """TPU v5e chip constants (the dry-run/roofline hardware).
+
+    Peaks from Google Cloud's "TPU v5e" documentation: 197 TFLOP/s bf16,
+    393 TOP/s int8, 16 GB HBM at 819 GB/s. ``device_kinds`` are the
+    ``jax.Device.device_kind`` strings these peaks describe.
+    """
     name: str = "v5e"
+    device_kinds: tuple[str, ...] = ("TPU v5 lite",)
     peak_flops: float = 197e12          # bf16 FLOP/s per chip
     hbm_bw: float = 819e9               # bytes/s per chip
     ici_bw: float = 50e9                # bytes/s per link
@@ -136,6 +142,19 @@ class TPUTarget:
 
 
 V5E = TPUTarget()
+
+
+def tpu_target_for(device) -> TPUTarget:
+    """The peaks-table entry for a JAX TPU ``device``, by its
+    ``device_kind``. A device that is not in the table is an error, not a
+    default: planning or measuring against another chip's peaks would
+    silently mislabel every number."""
+    if device.device_kind in V5E.device_kinds:
+        return V5E
+    raise ValueError(
+        f"no peaks known for device kind {device.device_kind!r} "
+        f"(platform {device.platform!r}); known kinds: "
+        f"{list(V5E.device_kinds)}")
 
 
 # ---------------------------------------------------------------------------

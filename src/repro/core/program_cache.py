@@ -18,9 +18,9 @@ backend, interpret, opt_level, donate_input)``:
 * ``backend`` ("xla" | "pallas") and the *resolved* Pallas interpret flag
   join the key because they change the lowering itself — the same schedule
   lowered through the XLA ops and through the Pallas PE kernels are two
-  different compiled artifacts. ``interpret=None`` is resolved (off-TPU ->
-  interpret mode) *before* keying so an auto-selected fallback and an
-  explicit ``interpret=True`` share one entry.
+  different compiled artifacts. ``interpret=None`` is resolved from the
+  executor's device (interpret mode off-TPU) *before* keying, so a
+  resolved ``None`` and the equivalent explicit value share one entry.
 * ``opt_level`` (0 = literal per-block lowering, 1 = the lowering
   optimizer's fused/stacked forms — see ``core/executor.py``) joins the key
   for the same reason: the two levels are different compiled artifacts, and
@@ -93,7 +93,7 @@ def cache_key(program: Program, *, batch: int, dtype,
     layer (``core/aot.py``) reuse the exact same identity on disk, and what
     the key-stability property tests pin down.
     """
-    backend, interpret = resolve_backend(backend, interpret)
+    backend, interpret = resolve_backend(backend, interpret, mesh)
     opt_level = resolve_opt_level(opt_level)
     if mesh is not None and mesh_device_count(mesh) == 1:
         mesh = None
@@ -200,7 +200,7 @@ class ProgramCache:
         if fallback:
             with self._lock:
                 self.stats.fallbacks += 1
-        backend, interpret = resolve_backend(backend, interpret)
+        backend, interpret = resolve_backend(backend, interpret, mesh)
         opt_level = resolve_opt_level(opt_level)
         # a 1-device mesh lowers identically to no mesh — normalize before
         # keying so the two spellings share one entry

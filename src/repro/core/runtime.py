@@ -96,11 +96,11 @@ class HybridRuntime:
         compute helper per backend. ``use_pallas=True`` is the legacy
         spelling of ``backend="pallas"``.
     interpret:
-        Pallas interpret-mode override. ``None`` (default) auto-selects:
-        interpret mode everywhere except real TPU hardware, so the same
-        Program runs on a CPU test container. A non-None value with the
-        XLA backend raises ``ValueError`` (it would otherwise be silently
-        meaningless).
+        Pallas interpret-mode override. ``None`` (default) resolves from
+        the device the executor runs on: compiled kernels on a TPU,
+        interpret mode elsewhere, so the same Program runs in the CPU test
+        suite. A non-None value with the XLA backend raises
+        ``ValueError`` (it would otherwise be silently meaningless).
     opt_level:
         Lowering-optimizer level for the cached jitted executor: ``1``
         (default) fuses each layer's per-block loop into a whole-layer PE

@@ -1,27 +1,37 @@
 """Shared helpers for Pallas TPU kernels.
 
 All kernels in this package are written against the TPU backend
-(``pl.pallas_call`` with explicit ``BlockSpec`` VMEM tiling) and validated on
-CPU with ``interpret=True``.  ``INTERPRET`` flips interpret mode globally so the
-whole test-suite runs on the CPU container while the lowering path stays
-TPU-shaped.
+(``pl.pallas_call`` with explicit ``BlockSpec`` VMEM tiling). A kernel
+called with ``interpret=None`` resolves the mode when it is called, from the
+device the computation runs on (:func:`interpret_default`): compiled on a
+TPU, the Pallas interpreter everywhere else (the CPU test suite). Nothing
+here touches a JAX backend at import time.
 """
 from __future__ import annotations
 
-import functools
-import os
-
 import jax
-import jax.numpy as jnp
-
-# Interpret unless we are actually on TPU hardware.
-INTERPRET = jax.default_backend() != "tpu"
 
 # TPU hardware constants (v5e) used for block-shape heuristics.
 LANE = 128          # last-dim tiling (VREG lane count, MXU edge)
 SUBLANE = 8         # second-to-last dim tiling for fp32
 SUBLANE_I8 = 32     # second-to-last dim tiling for int8 (min tile 32x128)
-VMEM_BYTES = 128 * 1024 * 1024  # per-core VMEM budget (v5e ~128MB)
+
+
+def interpret_default(device=None) -> bool:
+    """Whether a Pallas kernel placed on ``device`` runs in interpret mode.
+
+    ``device`` defaults to JAX's default device. On a TPU the kernel is
+    compiled (and a kernel the compiler refuses raises); on any other
+    platform it runs in the Pallas interpreter.
+    """
+    if device is None:
+        device = jax.devices()[0]
+    return device.platform != "tpu"
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``interpret`` as given, or :func:`interpret_default` for ``None``."""
+    return interpret_default() if interpret is None else bool(interpret)
 
 
 def cdiv(a: int, b: int) -> int:
@@ -30,19 +40,3 @@ def cdiv(a: int, b: int) -> int:
 
 def round_up(a: int, b: int) -> int:
     return cdiv(a, b) * b
-
-
-def pad_to(x: jax.Array, axis: int, multiple: int, value=0.0) -> jax.Array:
-    """Zero-pad ``x`` along ``axis`` up to the next multiple of ``multiple``."""
-    size = x.shape[axis]
-    target = round_up(size, multiple)
-    if target == size:
-        return x
-    pads = [(0, 0)] * x.ndim
-    pads[axis] = (0, target - size)
-    return jnp.pad(x, pads, constant_values=value)
-
-
-@functools.lru_cache(None)
-def is_cpu() -> bool:
-    return jax.default_backend() == "cpu"

@@ -13,9 +13,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
-from repro.kernels.common import INTERPRET
+from repro.kernels.common import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -72,8 +71,6 @@ def flash_attention_kernel(
     kv_len: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = INTERPRET
     bh, sq, d = q.shape
     _, skv, _ = k.shape
     assert sq % bq == 0 and skv % bk == 0
@@ -99,7 +96,7 @@ def flash_attention_kernel(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -24,8 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-from repro.kernels.common import INTERPRET, LANE, SUBLANE_I8, round_up
+from repro.kernels.common import LANE, SUBLANE_I8, resolve_interpret, round_up
 
 
 def _qmm_kernel(a_ref, b_ref, bias_ref, mult_ref, o_ref, acc_ref, *,
@@ -83,7 +82,7 @@ def _qmm(a, b, bias, mult_vec, *, relu: bool, interpret: bool):
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int8),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, bias2, mult2)
@@ -102,12 +101,11 @@ def quantized_matmul(
     relu: bool = False,
     interpret: bool | None = None,
 ) -> jax.Array:              # (M, N) int8
-    if interpret is None:
-        interpret = INTERPRET
     assert a.dtype == jnp.int8 and b.dtype == jnp.int8, (a.dtype, b.dtype)
     m, k = a.shape
     k2, n = b.shape
     assert k == k2 and bias.shape == (n,), (a.shape, b.shape, bias.shape)
     mult_vec = jnp.broadcast_to(
         jnp.asarray(mult, jnp.float32), (n,))     # scalar -> uniform vector
-    return _qmm(a, b, bias, mult_vec, relu=relu, interpret=bool(interpret))
+    return _qmm(a, b, bias, mult_vec, relu=relu,
+                interpret=resolve_interpret(interpret))

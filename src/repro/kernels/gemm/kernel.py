@@ -21,8 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-from repro.kernels.common import INTERPRET
+from repro.kernels.common import resolve_interpret
 
 
 def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_kb: int):
@@ -79,8 +78,6 @@ def batched_matmul_kernel(
     interpret: bool | None = None,
 ) -> jax.Array:             # (G, M, N)
     """Raw pallas_call wrapper. Shapes must already be padded to block multiples."""
-    if interpret is None:
-        interpret = INTERPRET
     g, m, k = a.shape
     g2, k2, n = b.shape
     assert g == g2 and k == k2, (a.shape, b.shape)
@@ -102,7 +99,7 @@ def batched_matmul_kernel(
     else:
         raise ValueError(f"unknown dataflow {dataflow!r}")
 
-    compiler_params = tpu_compiler_params(
+    compiler_params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
     )
     in_specs = [
@@ -125,5 +122,5 @@ def batched_matmul_kernel(
         out_shape=jax.ShapeDtypeStruct((g, m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=compiler_params,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*operands)

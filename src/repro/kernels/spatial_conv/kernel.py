@@ -24,8 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-from repro.kernels.common import INTERPRET
+from repro.kernels.common import resolve_interpret
 
 
 def _conv_gemm_body(p_ref, w_ref, bias_ref, o_ref, acc_ref, *,
@@ -62,8 +61,6 @@ def conv_gemm_kernel(
     interpret: bool | None = None,
 ) -> jax.Array:             # (T, K)
     """Raw pallas_call wrapper. Shapes must already be padded to block multiples."""
-    if interpret is None:
-        interpret = INTERPRET
     t, crs = patches.shape
     crs2, k = weights.shape
     assert crs == crs2, (patches.shape, weights.shape)
@@ -97,7 +94,7 @@ def conv_gemm_kernel(
         out_specs=pl.BlockSpec((bm, bn), o_map),
         out_shape=jax.ShapeDtypeStruct((t, k), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(patches, weights, bias.reshape(1, -1))

@@ -1,12 +1,15 @@
 """Pallas kernel bodies for Winograd input/output transforms.
 
-Input transform:  tiles (T, PT, PT, C) -> V (PT^2, T, C)   [V = B^T d B]
-Output transform: M (PT^2, T, K)       -> Y (T, m, m, K)   [Y = A^T M A]
+Input transform:  tiles (PT, PT, T, C) -> V (PT^2, T, C)   [V = B^T d B]
+Output transform: M (PT^2, T, K)       -> Y (m, m, T, K)   [Y = A^T M A]
 
-Both are blocked over (tile, channel); the tiny PT x PT transform matrices are
-baked into the kernel as constants (on TPU these contractions are VPU work —
-they are reductions of length 4 or 6, far below MXU granularity, exactly like
-the adder trees the paper uses next to its DSP GEMM cores).
+Both are blocked over (tile, channel). The tile-position axes lead, so every
+operand the body touches is a 2-D ``(tiles, channels)`` slab aligned to the
+TPU's (8, 128) tiling. The PT x PT transform matrices are compile-time
+constants, so each transform unrolls into scalar multiply-adds of whole slabs
+(zero coefficients skipped) — VPU work, reductions of length 4 or 6 far below
+MXU granularity, exactly like the adder trees the paper uses next to its DSP
+GEMM cores. Both transforms are separable: rows first, then columns.
 
 The output transform optionally fuses bias add + ReLU — the paper's
 accumulating-buffer epilogue — saving one full HBM round-trip of the
@@ -21,36 +24,64 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.winograd import pt_for, transform_matrices
-from repro.kernels.common import INTERPRET, round_up
+from repro.kernels.common import resolve_interpret
 
 
-def _input_transform_kernel(bt_ref, d_ref, v_ref, *, m: int):
-    bt = bt_ref[...].astype(jnp.float32)          # (PT, PT) = B^T
-    d = d_ref[...].astype(jnp.float32)            # (BT, PT, PT, BC)
-    # V[i,j] = sum_{p,q} BT[i,p] * d[p,q] * BT[j,q]
-    v = jnp.einsum("ip,tpqc,jq->ijtc", bt, d, bt)
+def _lincomb(coeffs, terms):
+    """``sum_i coeffs[i] * terms[i]`` over constant coefficients, skipping
+    zeros and multiplying only where the coefficient is not +-1."""
+    acc = None
+    for c, t in zip(coeffs, terms):
+        c = float(c)
+        if c == 0.0:
+            continue
+        if c == 1.0:
+            term = t
+        elif c == -1.0:
+            term = -t
+        else:
+            term = c * t
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def _separable(left, right, grid):
+    """``out[i][j] = sum_{p,q} left[i,p] * grid[p][q] * right[j,q]``."""
+    rows = [[_lincomb(left[i], [grid[p][q] for p in range(len(grid))])
+             for q in range(len(grid[0]))] for i in range(len(left))]
+    return [[_lincomb(right[j], rows[i]) for j in range(len(right))]
+            for i in range(len(left))]
+
+
+def _input_transform_kernel(d_ref, v_ref, *, m: int):
+    btm, _, _ = transform_matrices(m)
     pt = pt_for(m)
-    bt_sz, _, _, bc = d.shape
-    v_ref[...] = v.reshape(pt * pt, bt_sz, bc).astype(v_ref.dtype)
+    d = [[d_ref[p, q].astype(jnp.float32) for q in range(pt)]
+         for p in range(pt)]
+    v = _separable(btm, btm, d)
+    for i in range(pt):
+        for j in range(pt):
+            v_ref[i * pt + j] = v[i][j].astype(v_ref.dtype)
 
 
-def _output_transform_kernel(at_ref, m_ref, b_ref, y_ref, *, m: int, relu: bool):
-    at = at_ref[...].astype(jnp.float32)          # (m, PT) = A^T
+def _output_transform_kernel(m_ref, b_ref, y_ref, *, m: int, relu: bool):
+    _, _, atm = transform_matrices(m)
     pt = pt_for(m)
-    mm = m_ref[...].astype(jnp.float32)           # (PT^2, BT, BK)
-    _, bt_sz, bk = mm.shape
-    mm = mm.reshape(pt, pt, bt_sz, bk)
-    y = jnp.einsum("ip,pqtk,jq->tijk", at, mm, at)  # (BT, m, m, BK)
-    y = y + b_ref[...].astype(jnp.float32)          # (1, 1, 1, BK) broadcast
-    if relu:
-        y = jnp.maximum(y, 0.0)
-    y_ref[...] = y.astype(y_ref.dtype)
+    mm = [[m_ref[p * pt + q].astype(jnp.float32) for q in range(pt)]
+          for p in range(pt)]
+    y = _separable(atm, atm, mm)
+    bias = b_ref[...].astype(jnp.float32)          # (1, BK) broadcast
+    for i in range(m):
+        for j in range(m):
+            out = y[i][j] + bias
+            if relu:
+                out = jnp.maximum(out, 0.0)
+            y_ref[i, j] = out.astype(y_ref.dtype)
 
 
 def input_transform_kernel(
-    tiles: jax.Array,  # (T, PT, PT, C) padded: T % bt == 0, C % bc == 0
+    tiles: jax.Array,  # (PT, PT, T, C) padded: T % bt == 0, C % bc == 0
     *,
     m: int,
     bt: int,
@@ -58,25 +89,19 @@ def input_transform_kernel(
     out_dtype=jnp.float32,
     interpret: bool | None = None,
 ) -> jax.Array:       # (PT^2, T, C)
-    if interpret is None:
-        interpret = INTERPRET
-    t, pt, _, c = tiles.shape
+    pt, _, t, c = tiles.shape
     assert pt == pt_for(m) and t % bt == 0 and c % bc == 0
-    grid = (t // bt, c // bc)
-    btm, _, _ = transform_matrices(m, jnp.float32)
     return pl.pallas_call(
         functools.partial(_input_transform_kernel, m=m),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((pt, pt), lambda ti, ci: (0, 0)),
-            pl.BlockSpec((bt, pt, pt, bc), lambda ti, ci: (ti, 0, 0, ci)),
-        ],
+        grid=(t // bt, c // bc),
+        in_specs=[pl.BlockSpec((pt, pt, bt, bc),
+                               lambda ti, ci: (0, 0, ti, ci))],
         out_specs=pl.BlockSpec((pt * pt, bt, bc), lambda ti, ci: (0, ti, ci)),
         out_shape=jax.ShapeDtypeStruct((pt * pt, t, c), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
-    )(btm, tiles)
+        interpret=resolve_interpret(interpret),
+    )(tiles)
 
 
 def output_transform_kernel(
@@ -89,26 +114,20 @@ def output_transform_kernel(
     relu: bool = False,
     out_dtype=jnp.float32,
     interpret: bool | None = None,
-) -> jax.Array:         # (T, m, m, K)
-    if interpret is None:
-        interpret = INTERPRET
+) -> jax.Array:         # (m, m, T, K)
     pt2, t, k = m_arr.shape
     pt = pt_for(m)
     assert pt2 == pt * pt and t % bt == 0 and k % bk == 0
-    grid = (t // bt, k // bk)
-    bias4 = bias.reshape(1, 1, 1, k)
-    _, _, atm = transform_matrices(m, jnp.float32)
     return pl.pallas_call(
         functools.partial(_output_transform_kernel, m=m, relu=relu),
-        grid=grid,
+        grid=(t // bt, k // bk),
         in_specs=[
-            pl.BlockSpec((m, pt), lambda ti, ki: (0, 0)),
             pl.BlockSpec((pt * pt, bt, bk), lambda ti, ki: (0, ti, ki)),
-            pl.BlockSpec((1, 1, 1, bk), lambda ti, ki: (0, 0, 0, ki)),
+            pl.BlockSpec((1, bk), lambda ti, ki: (0, ki)),
         ],
-        out_specs=pl.BlockSpec((bt, m, m, bk), lambda ti, ki: (ti, 0, 0, ki)),
-        out_shape=jax.ShapeDtypeStruct((t, m, m, k), out_dtype),
-        compiler_params=tpu_compiler_params(
+        out_specs=pl.BlockSpec((m, m, bt, bk), lambda ti, ki: (0, 0, ti, ki)),
+        out_shape=jax.ShapeDtypeStruct((m, m, t, k), out_dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
-    )(atm, m_arr, bias4)
+        interpret=resolve_interpret(interpret),
+    )(m_arr, bias.reshape(1, k))

@@ -2,7 +2,7 @@
 
 Pipeline (the paper's COMP-module datapath, Sec. 4.2):
 
-  tile extract (XLA gather)           — LOAD manager addressing
+  tile extract (XLA strided slices)   — LOAD manager addressing
   -> input_transform  (Pallas)        — LOAD manager online B^T d B
   -> batched GEMM, batch PT^2 (Pallas, kernels/gemm) — the PE, Eq. 2
   -> output_transform (Pallas, fused bias+ReLU)      — SAVE manager A^T M A
@@ -23,7 +23,6 @@ from repro.core.winograd import (
     R_WINO,
     decompose_kernel,
     pt_for,
-    tile_input,
     transform_weights,
 )
 from repro.kernels.common import LANE, SUBLANE, round_up
@@ -40,6 +39,44 @@ def _pick_tile_blocks(t: int, c: int, k: int) -> tuple[int, int, int]:
     bc = min(round_up(c, LANE), 256)
     bk = min(round_up(k, LANE), 256)
     return bt, bc, bk
+
+
+# The transforms hold PT^2 slabs of (tiles, channels) per grid step, in and
+# out, double-buffered: blocks of at most 128 x 128 keep that (36 x 64 KiB
+# each way at PT = 6) well inside the compiler's default scoped-VMEM limit,
+# where the GEMM's 256-wide blocks would not fit.
+_TRANSFORM_BLOCK = 128
+
+
+def _transform_block(b: int) -> int:
+    """The largest multiple of the sublane tile, at most _TRANSFORM_BLOCK,
+    that divides the GEMM block ``b``, so both grids cover the same padded
+    extent (a 200-tile GEMM block gets 40-tile transform blocks)."""
+    return max(d for d in range(SUBLANE, min(b, _TRANSFORM_BLOCK) + 1,
+                                SUBLANE) if b % d == 0)
+
+
+def _tiles_ptpt(x: jax.Array, m: int):
+    """Overlapping PT x PT input tiles at stride m, tile-position axes first.
+
+    ``x`` is already padded for a VALID conv. Returns ``(tiles, (nh, nw))``
+    with tiles shaped (PT, PT, N*nh*nw, C): one strided slice per tile
+    position, so no gather is needed and each (p, q) slab is a 2-D
+    (tiles, channels) matrix the transform kernel reads whole.
+    """
+    pt = pt_for(m)
+    n, h, w, c = x.shape
+    nh, nw = -(-(h - R_WINO + 1) // m), -(-(w - R_WINO + 1) // m)
+    hp, wp = (nh - 1) * m + pt, (nw - 1) * m + pt
+    x = jnp.pad(x, ((0, 0), (0, hp - h), (0, wp - w), (0, 0)))
+    rows = []
+    for p in range(pt):
+        row = []
+        for q in range(pt):
+            sl = x[:, p:p + (nh - 1) * m + 1:m, q:q + (nw - 1) * m + 1:m]
+            row.append(sl.reshape(n * nh * nw, c))
+        rows.append(jnp.stack(row))
+    return jnp.stack(rows), (nh, nw)
 
 
 def _pad_for_conv(x_nhwc, rr, ss, padding):
@@ -60,9 +97,10 @@ def _finish_output(m_acc, bias, *, m, bt, bk, relu, interpret, geom,
     the reshape/crop arithmetic can't drift."""
     n, nh, nw, t, tp = geom
     bias_p = jnp.pad(bias.astype(jnp.float32), (0, kp - k))
-    y = output_transform_kernel(m_acc, bias_p, m=m, bt=bt, bk=bk, relu=relu,
-                                out_dtype=jnp.float32, interpret=interpret)
-    y = y[:t].reshape(n, nh, nw, m, m, kp).transpose(0, 1, 3, 2, 4, 5)
+    y = output_transform_kernel(
+        m_acc, bias_p, m=m, bt=_transform_block(bt), bk=_transform_block(bk),
+        relu=relu, out_dtype=jnp.float32, interpret=interpret)  # (m,m,Tp,Kp)
+    y = y[:, :, :t].reshape(m, m, n, nh, nw, kp).transpose(2, 3, 0, 4, 1, 5)
     y = y.reshape(n, nh * m, nw * m, kp)[:, :ho, :wo, :k]
     return y.astype(out_dtype)
 
@@ -73,18 +111,16 @@ def _wino_conv_piece(x, u_flat, m, t_blocks, out_dtype, dataflow, interpret):
     u_flat: (PT^2, Cp, Kp) transformed weights (already channel-padded).
     Returns M-space output (PT^2, T, Kp) accumulated later, plus tile geometry.
     """
-    tiles, (nh, nw) = tile_input(x, m)
+    tiles, (nh, nw) = _tiles_ptpt(x, m)
     n = x.shape[0]
-    pt = pt_for(m)
-    c = tiles.shape[-1]
-    t = n * nh * nw
+    _, _, t, c = tiles.shape
     bt, bc, bk = t_blocks
     tp, cp = round_up(t, bt), round_up(c, bc)
-    tiles = tiles.reshape(t, pt, pt, c)
     if (tp, cp) != (t, c):
-        tiles = jnp.pad(tiles, ((0, tp - t), (0, 0), (0, 0), (0, cp - c)))
-    v = input_transform_kernel(tiles, m=m, bt=bt, bc=bc,
-                               out_dtype=jnp.float32, interpret=interpret)
+        tiles = jnp.pad(tiles, ((0, 0), (0, 0), (0, tp - t), (0, cp - c)))
+    v = input_transform_kernel(
+        tiles, m=m, bt=_transform_block(bt), bc=_transform_block(bc),
+        out_dtype=jnp.float32, interpret=interpret)
     mm = batched_matmul_kernel(
         v, u_flat, bm=bt, bn=bk, bk=bc, dataflow=dataflow,
         out_dtype=jnp.float32, interpret=interpret)        # (PT^2, Tp, Kp)
@@ -202,21 +238,24 @@ def winograd_apply_pretransformed_pallas(
 
 
 def input_transform(tiles, m, **kw):
-    """Padded public wrapper for the input-transform Pallas kernel."""
+    """Padded public wrapper for the input-transform Pallas kernel:
+    (T, PT, PT, C) -> (PT^2, T, C)."""
     t, pt, _, c = tiles.shape
-    bt, bc, _ = _pick_tile_blocks(t, c, c)
+    bt, bc, _ = map(_transform_block, _pick_tile_blocks(t, c, c))
     tp, cp = round_up(t, bt), round_up(c, bc)
-    tiles = jnp.pad(tiles, ((0, tp - t), (0, 0), (0, 0), (0, cp - c)))
+    tiles = jnp.pad(tiles.transpose(1, 2, 0, 3),
+                    ((0, 0), (0, 0), (0, tp - t), (0, cp - c)))
     v = input_transform_kernel(tiles, m=m, bt=bt, bc=bc, **kw)
     return v[:, :t, :c]
 
 
 def output_transform(m_arr, bias, m, relu=False, **kw):
-    """Padded public wrapper for the output-transform Pallas kernel."""
+    """Padded public wrapper for the output-transform Pallas kernel:
+    (PT^2, T, K), (K,) -> (T, m, m, K)."""
     pt2, t, k = m_arr.shape
-    bt, _, bk = _pick_tile_blocks(t, k, k)
+    bt, _, bk = map(_transform_block, _pick_tile_blocks(t, k, k))
     tp, kp = round_up(t, bt), round_up(k, bk)
     m_arr = jnp.pad(m_arr, ((0, 0), (0, tp - t), (0, kp - k)))
     bias_p = jnp.pad(bias.astype(jnp.float32), (0, kp - k))
     y = output_transform_kernel(m_arr, bias_p, m=m, bt=bt, bk=bk, relu=relu, **kw)
-    return y[:t, :, :, :k]
+    return y[:, :, :t, :k].transpose(2, 0, 1, 3)

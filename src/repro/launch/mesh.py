@@ -4,7 +4,14 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names, **kwargs):
+    """``jax.make_mesh`` with Auto axis types: the executors partition with
+    ``shard_map`` and ``NamedSharding``, not the explicit-sharding mode that
+    ``jax.make_mesh`` defaults to."""
+    kwargs.setdefault("axis_types",
+                      (jax.sharding.AxisType.Auto,) * len(axis_names))
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.parallel.sharding import make_rules, use_rules
 from repro.train import steps as steps_lib
@@ -115,9 +116,10 @@ def serve_cnn(arch: str = "vgg16", *, reduced: bool = True, batch: int = 8,
     keeps the legacy multi-Program path for comparison, and ``session=True``
     additionally drives requests through the batching (pipelined-dispatch)
     ``ServingSession``. ``backend="pallas"`` serves through the Pallas PE
-    kernels (interpret-mode off-TPU) instead of the XLA lowering;
-    ``opt_level=0`` disables the lowering optimizer (literal per-block
-    lowering — the reference the fused default is tested against).
+    kernels (compiled on a TPU, interpret mode elsewhere) instead of the
+    XLA lowering; ``opt_level=0`` disables the lowering optimizer (literal
+    per-block lowering — the reference the fused default is tested
+    against).
     ``dtype="int8"`` serves the quantized accelerator (post-training
     calibration on the request distribution, int8 PEs with fused
     requantize, int8-aware DSE — see ``docs/ARCHITECTURE.md``).
@@ -139,6 +141,11 @@ def serve_cnn(arch: str = "vgg16", *, reduced: bool = True, batch: int = 8,
             "glue between linear CONV runs) — a residual topology has no "
             "such segmentation; resnet18 serves single-Program only")
     iters = max(1, iters)
+    dse_target = getattr(pm, CNN_TARGETS[target])
+    if target == "tpu" and jax.devices()[0].platform == "tpu":
+        # serving on a chip: plan against the peaks of the chip that is
+        # there, and refuse one the table does not know
+        dse_target = pm.tpu_target_for(jax.devices()[0])
     img, scale = (64, 8) if reduced else (224, 1)
     n_classes = 10 if reduced else 1000
     if arch == "resnet18":
@@ -151,8 +158,8 @@ def serve_cnn(arch: str = "vgg16", *, reduced: bool = True, batch: int = 8,
     t0 = time.monotonic()
     # int8 calibrates on the request distribution itself — the serving
     # analog of calibrating on a training-set slice
-    acc = api.Accelerator.build(specs, target=getattr(pm, CNN_TARGETS[target]),
-                                batch=batch, seed=seed, segmented=segmented,
+    acc = api.Accelerator.build(specs, target=dse_target, batch=batch,
+                                seed=seed, segmented=segmented,
                                 backend=backend, opt_level=opt_level,
                                 dtype=dtype,
                                 calib=x_np if dtype == "int8" else None)
@@ -262,7 +269,8 @@ def main():
                          "busy; 'bucketed' is the legacy fixed window")
     ap.add_argument("--backend", default="xla", choices=("xla", "pallas"),
                     help="PE implementation the executor lowers through "
-                         "(pallas runs interpret-mode off-TPU)")
+                         "(pallas: compiled kernels on a TPU, the Pallas "
+                         "interpreter elsewhere)")
     ap.add_argument("--dtype", default="float32",
                     choices=("float32", "int8"),
                     help="CNN serving precision: int8 builds the quantized "
@@ -283,6 +291,7 @@ def main():
                          "provably equivalent; 0 keeps the literal "
                          "per-block lowering")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.arch.startswith("vgg") or args.arch.startswith("resnet"):
         y = serve_cnn(args.arch, reduced=args.reduced, batch=args.batch,
                       iters=args.iters,

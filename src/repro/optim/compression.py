@@ -54,10 +54,9 @@ def compressed_psum(grads, err_state, axis_names):
     per shard. Must run inside shard_map over ``axis_names`` (the DP axes).
     Returns (mean_grads, new_err_state).
     """
-    from repro.compat import axis_size
     n = 1
     for ax in axis_names:
-        n *= axis_size(ax)
+        n *= jax.lax.axis_size(ax)
 
     def one(g, e):
         corrected = g.astype(jnp.float32) + e

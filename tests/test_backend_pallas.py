@@ -1,5 +1,5 @@
 """The Pallas PE backend: numerical parity with the XLA lowering and the
-strict interpreter, cache-key separation, and the interpret-mode fallback.
+strict interpreter, cache-key separation, and interpret mode off the TPU.
 
 Tolerance contract (documented in docs/ARCHITECTURE.md): both backends
 compute the same blocked schedule in fp32 accumulation, but the Pallas
@@ -70,7 +70,7 @@ def test_resolve_backend_contract():
         resolve_backend("xla", True)
     backend, interp = resolve_backend("pallas", None)
     assert backend == "pallas"
-    # off-TPU the auto-selection must fall back to interpret mode
+    # off the TPU, interpret=None resolves to interpret mode
     if jax.default_backend() != "tpu":
         assert interp is True
     assert resolve_backend("pallas", False) == ("pallas", False)
@@ -78,10 +78,24 @@ def test_resolve_backend_contract():
         resolve_backend("cuda", None)
 
 
+@pytest.mark.parametrize("platform,interpret", [("tpu", False),
+                                                ("cpu", True)])
+def test_interpret_resolves_from_executor_device(platform, interpret):
+    """interpret=None is decided by the device the executor runs on (the
+    mesh's first device), not by a flag fixed when the package was
+    imported: compiled kernels on a TPU, the interpreter elsewhere."""
+    import types
+    dev = types.SimpleNamespace(platform=platform, device_kind="x")
+    mesh = types.SimpleNamespace(devices=np.array([dev], dtype=object))
+    assert resolve_backend("pallas", None, mesh) == ("pallas", interpret)
+    assert resolve_backend("pallas", not interpret, mesh) == (
+        "pallas", not interpret)
+
+
 def test_accelerator_pallas_matches_xla_and_interpreter(vgg_pallas_setup):
     """The acceptance gate: Accelerator.build(backend="pallas") over the full
     reduced VGG16 == the XLA backend == the strict interpreter, with the
-    interpret-mode fallback (CPU CI) exercised by default."""
+    Pallas interpreter (the CPU suite) exercised by default."""
     cache, acc_xla, acc_pal, x = vgg_pallas_setup
     y_xla = np.asarray(acc_xla(x))
     y_pal = np.asarray(acc_pal(x))
@@ -95,7 +109,7 @@ def test_accelerator_pallas_matches_xla_and_interpreter(vgg_pallas_setup):
     ent = acc_pal.runtime.executor_entry(2, jnp.float32)[0]
     assert ent.backend == "pallas"
     if jax.default_backend() != "tpu":
-        assert ent.interpret is True    # the CPU fallback actually engaged
+        assert ent.interpret is True    # the interpreter actually ran
 
 
 def test_strict_interpreter_pallas_backend_small_net():

@@ -153,6 +153,20 @@ def test_tpu_dse_vmem_constraint():
     assert working <= pm.V5E.vmem_bytes // 2
 
 
+@pytest.mark.parametrize("kind,known", [("TPU v5 lite", True),
+                                        ("TPU v4", False), ("cpu", False)])
+def test_tpu_target_for_device_kind(kind, known):
+    """The peaks table is keyed by device_kind: a v5e maps to V5E, and any
+    kind the table does not list is an error, not a default."""
+    import types
+    dev = types.SimpleNamespace(platform="tpu", device_kind=kind)
+    if known:
+        assert pm.tpu_target_for(dev) is pm.V5E
+    else:
+        with pytest.raises(ValueError, match="no peaks known"):
+            pm.tpu_target_for(dev)
+
+
 def test_estimated_latency_monotone_in_bandwidth():
     specs = conv_specs()
     lats = []

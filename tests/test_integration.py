@@ -156,7 +156,7 @@ def test_elastic_restore_resharding(tmp_path):
     """Checkpoint written on one mesh restores onto a different mesh."""
     tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
     ckpt_lib.save(str(tmp_path), 3, tree)
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1,), ("model",))
     sh = {"w": jax.NamedSharding(mesh, jax.sharding.PartitionSpec("model"))}
     restored, step = ckpt_lib.restore(str(tmp_path), tree, shardings=sh)

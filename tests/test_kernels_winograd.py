@@ -41,6 +41,7 @@ def test_output_transform(m, relu):
 CONV_CASES = [
     (1, 8, 8, 3, 4, 3),
     (2, 14, 14, 8, 16, 3),
+    (1, 28, 28, 4, 8, 3),    # m=2: 196 tiles, a GEMM block 128 does not divide
     (1, 12, 10, 4, 8, 5),    # kernel decomposition 5x5
     (1, 16, 16, 3, 4, 7),    # kernel decomposition 7x7
 ]

@@ -29,7 +29,7 @@ def test_shardmap_pallas_gemm():
     _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from repro.launch.mesh import make_mesh
         from repro.kernels.gemm import batched_matmul
         from repro.kernels.gemm.ref import batched_matmul_ref
         mesh = make_mesh((2, 4), ("data", "model"))
@@ -39,11 +39,11 @@ def test_shardmap_pallas_gemm():
         def local_mm(a, b):  # batch sharded over data, N sharded over model
             return batched_matmul(a, b)
 
-        mm = shard_map(local_mm, mesh=mesh,
-                       in_specs=(P("data", None, None),
-                                 P("data", None, "model")),
-                       out_specs=P("data", None, "model"),
-                       check_vma=False)  # pallas_call outputs carry no vma
+        mm = jax.shard_map(local_mm, mesh=mesh,
+                           in_specs=(P("data", None, None),
+                                     P("data", None, "model")),
+                           out_specs=P("data", None, "model"),
+                           check_vma=False)  # pallas_call outputs carry no vma
         out = mm(a, b)
         ref = batched_matmul_ref(a, b)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -62,7 +62,7 @@ def test_sharded_train_step_runs():
                                              use_rules)
         from repro.optim import adamw
         from repro.train import steps as steps_lib
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         rules = make_rules(mesh)
         cfg = get_config("minitron-8b").reduced()
@@ -89,7 +89,7 @@ def test_compressed_psum_matches_mean():
     _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from repro.launch.mesh import make_mesh
         from repro.optim.compression import compressed_psum, init_error_state
         mesh = make_mesh((8,), ("data",))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
@@ -100,8 +100,8 @@ def test_compressed_psum_matches_mean():
             mean, new_err = compressed_psum(grads, err, ("data",))
             return mean["w"]
 
-        out = shard_map(body, mesh=mesh, in_specs=P("data", None),
-                        out_specs=P())(g)
+        out = jax.shard_map(body, mesh=mesh, in_specs=P("data", None),
+                            out_specs=P())(g)
         ref = jnp.mean(g, axis=0)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=0.05, atol=0.02)

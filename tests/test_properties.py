@@ -89,7 +89,7 @@ def test_isa_fc_dims_roundtrip(d_in, d_out, relu, layer):
 
 @settings(**_SETTINGS)
 @given(
-    r=st.integers(0, 255), s=st.integers(0, 255), stride=st.integers(0, 255),
+    r=st.integers(1, 255), s=st.integers(1, 255), stride=st.integers(1, 255),
     relu=st.booleans(), layer=st.integers(0, 2 ** 16 - 1),
 )
 def test_isa_dw_geom_roundtrip(r, s, stride, relu, layer):
@@ -102,6 +102,15 @@ def test_isa_dw_geom_roundtrip(r, s, stride, relu, layer):
     back = decode(ins.encode())
     assert back == ins
     assert unpack_dw_geom(back.size) == (r, s, stride)
+
+
+@pytest.mark.parametrize("geom", [(0, 3, 1), (3, 0, 1), (3, 3, 0)])
+def test_isa_dw_geom_rejects_zero(geom):
+    """A zero kernel extent or stride is no depthwise geometry: packing it
+    raises instead of encoding an instruction no executor can run."""
+    from repro.core.isa import pack_dw_geom
+    with pytest.raises(ValueError):
+        pack_dw_geom(*geom)
 
 
 @settings(**_SETTINGS)

@@ -78,10 +78,10 @@ ROW_SCHEMAS: dict[str, set[str]] = {
                              "top1_agreement_resnet18",
                              "executor_interp_bitwise",
                              "dequant_max_abs_err", "backend_mode"},
-    # warm_over_cold_compile_ratio = warm-process warm_load_ms over
-    # cold-process compile_ms: both sides are fresh-interpreter wall
-    # clocks for the SAME program on the same host, so the ratio is
-    # machine-load-independent and gates as a lower-is-better key
+    # warm_over_cold_compile_ratio = warm-side warm_load_ms over cold-side
+    # compile_ms: both are wall clocks for the SAME program in one process
+    # from cleared caches, so the ratio is machine-load-independent and
+    # gates as a lower-is-better key
     "serving/aot_cold_start": {"cold_compile_ms", "warm_load_ms",
                                "warm_over_cold_compile_ratio",
                                "max_abs_diff"},
