@@ -21,7 +21,6 @@
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import json
 import os
@@ -29,6 +28,7 @@ import time
 
 from repro.core import perf_model as pm
 from repro.core.dse import DSEResult, run_fpga_dse, run_tpu_dse
+from repro.launch.compile_cache import without_compile_cache
 from repro.models.vgg import conv_specs, conv_segments, network_specs
 
 PAPER_GOPS = {"VU9P": 3375.7, "PYNQ-Z1": 83.3}
@@ -239,6 +239,7 @@ def _jaxpr_ops(jaxpr) -> int:
     return n
 
 
+@without_compile_cache()
 def run_fused_vs_blocked(*, img: int = 32, scale: int = 16, batch: int = 2,
                          iters: int = 20) -> list[dict]:
     """Lowering-optimizer payoff on the full reduced VGG16 (13 CONV +
@@ -501,6 +502,7 @@ def run_int8_vs_fp32(*, img: int = 32, scale: int = 16, batch: int = 2,
     }]
 
 
+@without_compile_cache()
 def run_serving_queue(*, img: int = 32, scale: int = 16, batch: int = 8,
                       n_requests: int = 128) -> list[dict]:
     """ServingSession throughput: single-image requests coalesced by the
@@ -589,23 +591,7 @@ def run_serving_queue(*, img: int = 32, scale: int = 16, batch: int = 8,
     }]
 
 
-@contextlib.contextmanager
-def _without_persistent_cache():
-    """Compile for real inside the block: JAX's persistent compilation
-    cache (which ``benchmarks.run`` turns on) would otherwise turn the
-    cold side into a cache read."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    try:
-        yield
-    finally:
-        jax.config.update("jax_enable_compilation_cache", enabled)
-        cc.reset_cache()
-
-
+@without_compile_cache()
 def run_aot_cold_start(*, img: int = 32, scale: int = 16,
                        batch: int = 8, n_req: int = 32) -> list[dict]:
     """AOT cold-start row: a session loading the serialized-executable
@@ -614,12 +600,14 @@ def run_aot_cold_start(*, img: int = 32, scale: int = 16,
     artifact layer exists for.
 
     Both sides run in this process, which holds the device: each starts
-    from ``jax.clear_caches()`` and a fresh ``ProgramCache``, and the cold
-    side compiles with the persistent compilation cache off. The row
-    records the cold side's ``compile_ms``, the warm side's
-    ``warm_load_ms`` (its ``compile_ms`` must be 0 — enforced here), their
-    ratio (gated lower-is-better by ``tools/bench_compare.py``; the issue
-    targets <= 0.10), session-ready wall clocks, and the max |diff|
+    from ``jax.clear_caches()`` and a fresh ``ProgramCache``, with the
+    persistent compilation cache off. The row records the cold side's
+    ``compile_ms``, the warm side's ``warm_load_ms`` (its ``compile_ms``
+    must be 0 — enforced here), their ratio (gated lower-is-better by
+    ``tools/bench_compare.py``; target <= 0.10), session-ready
+    wall clocks (from ``from_program`` to the last answer, in an already
+    started process: interpreter and JAX start-up are not in them), and
+    the max |diff|
     between the two sides' outputs — bitwise 0.0 by construction, since a
     deserialized executable IS the compiled program.
     """
@@ -655,8 +643,7 @@ def run_aot_cold_start(*, img: int = 32, scale: int = 16,
     with tempfile.TemporaryDirectory() as tmp:
         bundle = os.path.join(tmp, "bundle")
         acc.save_program(bundle, aot=True, buckets=(batch,))
-        with _without_persistent_cache():
-            cold = _serve(os.path.join(bundle, "program.json"))
+        cold = _serve(os.path.join(bundle, "program.json"))
         warm = _serve(bundle)
 
     if warm["compile_ms"] != 0.0:

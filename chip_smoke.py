@@ -27,17 +27,22 @@ import sys
 import time
 from pathlib import Path
 
-# The comparison bar, relative to the largest |logit| of the reference.
-# On the TPU an fp32 matmul or convolution at default precision runs on
-# one bf16 MXU pass: both operands are rounded to bf16 (unit roundoff
-# 2^-9 ~ 2e-3), which leaves each layer's output off by ~3e-3 of its scale
-# (sqrt(2) * 2^-9 for random-sign dot products), while the reference runs
-# at ``highest``. Over VGG16's 16 layers those errors compound to ~1e-2 of
-# the output scale, and a TPU v5e measured 2.3e-2 on the first batch; 5e-2
-# leaves room for that spread and still fails any wrong layout, schedule
-# or kernel, which moves outputs by O(1) of the output scale.
-REL_TOL = 5e-2
+# The comparison bars. On the TPU an fp32 matmul or convolution at default
+# precision runs on one bf16 MXU pass: both operands are rounded to bf16
+# (unit roundoff 2^-9 ~ 2e-3), which leaves each layer's output off by
+# ~3e-3 of its scale (sqrt(2) * 2^-9 for random-sign dot products), while
+# the reference runs at ``highest``. Over VGG16's 16 layers those errors
+# compound to 2-3% of the output scale: on a TPU v5e the sound runs read
+# at most 0.036 (max) and 0.032 (rms), at batch 32. Faults planted in the
+# served model at batch 8 read 0.041-0.049 rms for a dropped bias or one
+# layer's weights 3% high, 0.24 for flipped filter taps and 5.2 for a
+# sign error in a Winograd coefficient; a fault under ~3% rms sits inside
+# the bf16 noise and cannot be told from it at this precision.
+MAX_REL_TOL = 5e-2      # max|diff| / max|ref|
+RMS_REL_TOL = 4e-2      # rms(diff) / rms(ref)
+TOP1_MIN = 0.9          # share of requests whose top-1 class agrees
 IMG, N_CLASSES, BATCH = 224, 1000, 8
+N_REQUESTS = 4 * BATCH  # per steady round of the one-chip phases
 
 
 def fail(msg: str):
@@ -50,18 +55,32 @@ def log(msg: str):
 
 
 class CacheEvents:
-    """Counts JAX persistent-compilation-cache hits and writes."""
+    """Counts JAX persistent-compilation-cache hits and misses (a miss is
+    written back only when its compile took longer than JAX's threshold)."""
 
     def __init__(self, jax):
-        self.hits = self.writes = 0
+        self.hits = self.misses = 0
 
         def listen(event, **_):
             if event == "/jax/compilation_cache/cache_hits":
                 self.hits += 1
             elif event == "/jax/compilation_cache/cache_misses":
-                self.writes += 1
+                self.misses += 1
 
         jax.monitoring.register_event_listener(listen)
+
+
+def with_biases(params, seed):
+    """``params`` with seeded nonzero biases, so that a dropped or
+    misplaced bias moves the logits: layer l's bias is a tenth of the rms
+    its pre-activations would have on a unit-variance input (2^(-l/2):
+    fan-in-scaled weights keep the second moment, ReLU halves it)."""
+    import jax.numpy as jnp
+    import numpy as np
+    rng = np.random.default_rng(seed)
+    return [(w, jnp.asarray(0.1 * 2 ** (-l / 2)
+                            * rng.standard_normal(b.shape), jnp.float32))
+            for l, (w, b) in enumerate(params)]
 
 
 def check_outputs(name, y, ref):
@@ -70,14 +89,17 @@ def check_outputs(name, y, ref):
         raise AssertionError(f"{name}: output shape {y.shape} != {ref.shape}")
     if not np.all(np.isfinite(y)):
         raise AssertionError(f"{name}: non-finite outputs")
-    err = float(np.max(np.abs(y - ref)) / np.max(np.abs(ref)))
-    rms = float(np.sqrt(np.mean((y - ref) ** 2) / np.mean(ref ** 2)))
-    log(f"  {name}: max|diff| / max|ref| = {err!r} (bar {REL_TOL}), "
-        f"max|diff| = {float(np.max(np.abs(y - ref)))!r}, "
-        f"rms(diff) / rms(ref) = {rms!r}, "
-        f"top-1 agreement {float(np.mean(y.argmax(-1) == ref.argmax(-1)))!r}")
-    if not err <= REL_TOL:
-        raise AssertionError(f"{name}: relative error {err} > {REL_TOL}")
+    d = y - ref
+    r = {"max_rel": float(np.max(np.abs(d)) / np.max(np.abs(ref))),
+         "rms_rel": float(np.sqrt(np.mean(d ** 2) / np.mean(ref ** 2))),
+         "top1": float(np.mean(y.argmax(-1) == ref.argmax(-1)))}
+    log(f"  {name}: max|diff| / max|ref| = {r['max_rel']!r} (bar "
+        f"{MAX_REL_TOL}), rms(diff) / rms(ref) = {r['rms_rel']!r} (bar "
+        f"{RMS_REL_TOL}), top-1 agreement {r['top1']!r} (bar {TOP1_MIN}), "
+        f"max|diff| = {float(np.max(np.abs(d)))!r}")
+    if not (r["max_rel"] <= MAX_REL_TOL and r["rms_rel"] <= RMS_REL_TOL
+            and r["top1"] >= TOP1_MIN):
+        raise AssertionError(f"{name}: outputs off the reference: {r}")
 
 
 def check_ledger(name, st, n_requests):
@@ -222,13 +244,10 @@ def main(argv=None) -> int:
                     help="1: the xla and pallas phases on the first chip; "
                          "4: only the sharded session over four chips")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--requests", type=int, default=32,
-                    help="requests per steady round (one-chip phases)")
     ap.add_argument("--rounds", type=int, default=3,
-                    help="steady rounds of --requests (one-chip phases)")
+                    help=f"steady rounds of {N_REQUESTS} requests "
+                         f"(one-chip phases)")
     args = ap.parse_args(argv)
-    if args.requests % BATCH:
-        fail(f"--requests must be a multiple of {BATCH}")
 
     sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
     try:
@@ -261,8 +280,8 @@ def main(argv=None) -> int:
 
     specs = vgg.network_specs(img=IMG, scale=1, n_classes=N_CLASSES)
     t0 = time.monotonic()
-    params = api.random_params(specs, seed=args.seed)
-    log(f"random fan-in-scaled params (seed {args.seed}): "
+    params = with_biases(api.random_params(specs, seed=args.seed), args.seed)
+    log(f"random fan-in-scaled params with biases (seed {args.seed}): "
         f"{sum(w.size + b.size for w, b in params)} values in "
         f"{(time.monotonic() - t0) * 1e3!r} ms")
 
@@ -272,12 +291,12 @@ def main(argv=None) -> int:
     else:
         rng = np.random.default_rng(args.seed + 1)
         xs = rng.standard_normal(
-            (args.requests, IMG, IMG, 3)).astype(np.float32)
+            (N_REQUESTS, IMG, IMG, 3)).astype(np.float32)
         ref = reference_logits(jax, specs, params, xs)
         for backend in ("xla", "pallas"):
             serve_phase(jax, backend, specs, params, target, xs, ref,
                         args.rounds)
-    log(f"compile cache: {cache.hits} hits, {cache.writes} writes "
+    log(f"compile cache: {cache.hits} hits, {cache.misses} misses "
         f"in {cache_dir}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -10,6 +10,8 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the test workers all import this
 file.
 """
+import os
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -23,6 +25,7 @@ from repro.kernels.winograd.kernel import (
     input_transform_kernel,
     output_transform_kernel,
 )
+from repro.launch.compile_cache import without_compile_cache
 
 N = 8   # serving batch
 
@@ -30,6 +33,10 @@ N = 8   # serving batch
 @pytest.fixture(scope="module")
 def topo():
     from jax.experimental import topologies
+    # the TPU library would otherwise write compiler and driver logs
+    # under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("TPU_MIN_LOG_LEVEL", "3")
     try:
         return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -39,12 +46,10 @@ def topo():
 
 @pytest.fixture(scope="module")
 def one_chip(topo):
-    # a compile for a described chip is written to the persistent cache
-    # but cannot be read back without one: keep the cache out of it
-    enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", enabled)
+    # a compile for a described chip would be written to the persistent
+    # cache but could never be read back without one: keep the cache out
+    with without_compile_cache():
+        yield SingleDeviceSharding(topo.devices[0])
 
 
 # (kernel, operand shapes/dtypes): VGG16 at 224, batch 8
