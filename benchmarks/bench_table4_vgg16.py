@@ -556,14 +556,15 @@ def run_serving_queue(*, img: int = 32, scale: int = 16, batch: int = 8,
                 jax.block_until_ready(acc(xb))
             direct_bN_rps = max(direct_bN_rps,
                                 batch * iters / (time.monotonic() - t0))
-            s.stats.latencies_ms.clear()    # percentiles: this pass only
+            before = s.stats.snapshot()     # percentiles: this pass only
             t0 = time.monotonic()
             outs = s.run_many(reqs)
             jax.block_until_ready(outs[-1])
             rps = n_requests / (time.monotonic() - t0)
             if rps > session_rps:
                 session_rps = rps
-                p50, p95 = s.stats.p50_ms(), s.stats.p95_ms()
+                window = s.stats.snapshot() - before
+                p50, p95 = window.p50_ms(), window.p95_ms()
             t0 = time.monotonic()
             for _ in range(n_requests // 2):
                 jax.block_until_ready(acc(x1))
@@ -853,7 +854,7 @@ def run_fault_injection(*, img: int = 32, scale: int = 16, batch: int = 4,
         with acc.serve(max_batch=batch, buckets=(batch,), max_wait_ms=2.0,
                        warmup=True, fault_plan=fault_plan) as s:
             s.run_many(list(xs[:2 * batch]))        # warm pipeline threads
-            s.stats.latencies_ms.clear()
+            before = s.stats.snapshot()
             t0 = time.monotonic()
             futs = [s.submit(x) for x in xs]
             outs = []
@@ -863,9 +864,10 @@ def run_fault_injection(*, img: int = 32, scale: int = 16, batch: int = 4,
                 except Exception as e:  # noqa: BLE001 — typed resolution
                     outs.append(e)
             dt = time.monotonic() - t0
-            st = s.stats
             resolved = all(f.done() for f in futs)
-        return outs, dt, st, resolved
+        # read once the session has closed: the drain side counts a batch
+        # just after resolving its futures
+        return outs, dt, s.stats.snapshot() - before, resolved
 
     clean_outs, t_clean, st_clean, _ = _pass(None)
     faulty_outs, t_faulty, st_faulty, resolved = _pass(plan)

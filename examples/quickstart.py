@@ -84,8 +84,8 @@ def main():
                  for i, o in enumerate(outs))
         print(f"ServingSession: {session.stats.requests} requests in "
               f"{session.stats.batches} device batches "
-              f"({session.stats.padded_rows} padded rows, latency "
-              f"p50 {session.stats.p50_ms():.2f}ms "
+              f"({session.stats.padded_rows} padded rows, whole-session "
+              f"latency p50 {session.stats.p50_ms():.2f}ms "
               f"p95 {session.stats.p95_ms():.2f}ms); "
               f"rows match = {ok}")
         assert ok

@@ -80,6 +80,7 @@ from repro.serving import (
     PipelineCrashed,
     ThreadSupervisor,
 )
+from repro.serving.telemetry import LogHistogram, SpanRecorder
 
 PROGRAM_FORMAT = "hybriddnn-program/v1"
 
@@ -776,18 +777,25 @@ class SessionStats:
     # EVERY device it spans; a single-device batch counts on its one device
     # — so the table reads as per-device occupancy of the fleet.
     device_batches: dict = dataclasses.field(default_factory=dict)
-    # per-request latency samples (submit -> result ready), most recent
-    # window only — enough for steady-state percentiles without unbounded
-    # growth on a long-lived session. Appends (drain thread) and percentile
-    # reads (any caller) share _lat_lock: sorting a deque the drain thread
-    # is appending to would raise "deque mutated during iteration".
-    latencies_ms: deque = dataclasses.field(
-        default_factory=lambda: deque(maxlen=4096))
-    # per-request queue-wait samples (submit -> admitted into a dispatched
-    # device batch) — the scheduler-health metric: continuous batching keeps
-    # this bounded by the batching window even under backpressure
-    waits_ms: deque = dataclasses.field(
-        default_factory=lambda: deque(maxlen=4096))
+    # whole-life timing, always on: per-request submit -> result latency
+    # and submit -> dispatch queue wait (the scheduler-health metric:
+    # continuous batching keeps it bounded by the batching window even
+    # under backpressure), in log-spaced bins that lose no sample however
+    # long the session lives (repro.serving.telemetry)
+    latency_hist: LogHistogram = dataclasses.field(
+        default_factory=LogHistogram)
+    wait_hist: LogHistogram = dataclasses.field(default_factory=LogHistogram)
+    # nanoseconds the host spent per phase, summed: staging on the callers'
+    # threads (validate, int8 quantize, copy), assembling and launching on
+    # the dispatch side, scattering results (callbacks included) on the
+    # drain side. stage_ns is written under the session's admission lock,
+    # the rest under _lat_lock.
+    stage_ns: int = 0
+    assemble_ns: int = 0
+    launch_ns: int = 0
+    deliver_ns: int = 0
+    # counter writes from several threads and histogram reads share
+    # _lat_lock
     _lat_lock: threading.Lock = dataclasses.field(
         default_factory=threading.Lock, repr=False)
 
@@ -799,42 +807,47 @@ class SessionStats:
         with self._lat_lock:
             setattr(self, name, getattr(self, name) + k)
 
-    def record_latency(self, ms: float):
+    def snapshot(self) -> "SessionStats":
+        """A copy of every counter and histogram. Two snapshots difference
+        to the stats of the window between them (``later - earlier``),
+        whose percentiles read that window alone."""
         with self._lat_lock:
-            self.latencies_ms.append(ms)
+            return dataclasses.replace(
+                self, latency_hist=self.latency_hist.copy(),
+                wait_hist=self.wait_hist.copy(),
+                device_batches=dict(self.device_batches),
+                _lat_lock=threading.Lock())
 
-    def record_latencies(self, ms_list):
-        """Batch append — one lock acquisition per device batch, not per
-        request (the drain thread calls this on the completion hot path)."""
-        with self._lat_lock:
-            self.latencies_ms.extend(ms_list)
+    def __sub__(self, earlier: "SessionStats") -> "SessionStats":
+        out = {}
+        for f in dataclasses.fields(self):
+            if f.name == "_lat_lock":
+                continue
+            a, b = getattr(self, f.name), getattr(earlier, f.name)
+            out[f.name] = ({k: v - b.get(k, 0) for k, v in a.items()}
+                           if isinstance(a, dict) else a - b)
+        return SessionStats(**out)
 
-    def record_waits(self, ms_list):
+    def _pct(self, hist: LogHistogram, q: float) -> float:
         with self._lat_lock:
-            self.waits_ms.extend(ms_list)
-
-    def _pct(self, xs_deque, q: float) -> float:
-        with self._lat_lock:
-            xs = sorted(xs_deque)
-        if not xs:
-            return 0.0
-        return xs[min(len(xs) - 1, int(q * len(xs)))]
+            return hist.percentile(q)
 
     def p50_ms(self) -> float:
-        """Median request latency over the recent window."""
-        return self._pct(self.latencies_ms, 0.50)
+        """Median submit-to-result latency over the session's life (or a
+        snapshot difference's window), to within one 4.4% bin."""
+        return self._pct(self.latency_hist, 0.50)
 
     def p95_ms(self) -> float:
-        """95th-percentile request latency over the recent window."""
-        return self._pct(self.latencies_ms, 0.95)
+        """95th-percentile submit-to-result latency, as ``p50_ms``."""
+        return self._pct(self.latency_hist, 0.95)
 
     def wait_p50_ms(self) -> float:
-        """Median queue wait (submit -> dispatch) over the recent window."""
-        return self._pct(self.waits_ms, 0.50)
+        """Median queue wait (submit -> dispatch), as ``p50_ms``."""
+        return self._pct(self.wait_hist, 0.50)
 
     def wait_p95_ms(self) -> float:
-        """95th-percentile queue wait over the recent window."""
-        return self._pct(self.waits_ms, 0.95)
+        """95th-percentile queue wait, as ``p50_ms``."""
+        return self._pct(self.wait_hist, 0.95)
 
     def occupancy(self) -> float:
         """Real-row fraction of all dispatched device rows (1.0 = no
@@ -992,10 +1005,17 @@ class ServingSession:
       bucket. Kept as the reference the scheduler tests compare against.
 
     ``stats`` records, besides request/batch counts, the trace+compile
-    time spent on warmup and first-use buckets (``compile_ms``), recent
-    windows of per-request submit-to-result latency (``p50_ms()`` /
-    ``p95_ms()``) and queue wait (``wait_p50_ms()``), per-device batch
-    counts (``device_batches``) and padding ``occupancy()``.
+    time spent on warmup and first-use buckets (``compile_ms``), per-device
+    batch counts (``device_batches``), padding ``occupancy()``, and over
+    the session's whole life: log-spaced histograms of per-request
+    submit-to-result latency (``latency_hist``; ``p50_ms()`` /
+    ``p95_ms()``) and queue wait (``wait_hist``; ``wait_p50_ms()`` /
+    ``wait_p95_ms()``), each to within one 4.4% bin, and the host's busy
+    nanoseconds per phase (``stage_ns``, ``assemble_ns``, ``launch_ns``,
+    ``deliver_ns``). ``stats.snapshot()`` copies them; two snapshots
+    difference to one window's stats. ``record_spans()`` records the host
+    spans of each request and device batch on the ``time.time_ns`` clock,
+    for laying over a profiler trace of the device.
 
     ``slot_pool`` shares the device-pipeline slots with other sessions — a
     :class:`Fleet` passes one pool to every tenant model so device slots
@@ -1100,6 +1120,8 @@ class ServingSession:
         self._guard_numerics = bool(guard_numerics)
         self._faults = fault_plan
         self._rid_counter = itertools.count()
+        self._batch_seq = itertools.count()
+        self._spans: SpanRecorder | None = None   # see record_spans()
         self._deadlines = DeadlineTable()
         self._backend_tag = getattr(acc, "backend", "xla") or "xla"
         self._fallback_entries: dict[int, Any] = {}  # lazy XLA degradation
@@ -1323,7 +1345,7 @@ class ServingSession:
             if r.fut is None or not r.fut.done())
         return len(self._pending) >= self.queue_limit
 
-    def _enqueue(self, reqs: list[_Request]):
+    def _enqueue(self, reqs: list[_Request], stage_ns: int):
         """Admission control: bounded queue with shed-or-block overflow,
         deadline registration, exact ``submitted`` accounting."""
         st = self.stats
@@ -1331,6 +1353,7 @@ class ServingSession:
         with self._cv:
             if self._closed:
                 raise RuntimeError("ServingSession is closed")
+            st.stage_ns += stage_ns
             for req in reqs:
                 if self.queue_limit is not None and self._queue_full():
                     if self.on_overload == "block":
@@ -1355,6 +1378,44 @@ class ServingSession:
             with self._sup_cv:   # new earliest deadline: shorten the nap
                 self._sup_cv.notify_all()
 
+    def _stage_all(self, xs, deadline_ms: float | None, fut=Future
+                   ) -> tuple[list[_Request], int]:
+        """Stage + wrap the requests of one call on the caller's thread;
+        returns them and the nanoseconds it took (one ``session.stage``
+        span, ``ref`` = the first request id)."""
+        t0 = time.perf_counter_ns()
+        now = time.monotonic()
+        reqs = [self._make_request(x, fut(), now, deadline_ms) for x in xs]
+        dt = time.perf_counter_ns() - t0
+        spans = self._spans
+        if spans is not None and reqs:
+            spans.add("session.stage", dt, reqs[0].rid)
+        return reqs, dt
+
+    @contextmanager
+    def record_spans(self):
+        """Record the session's host spans while the block runs; yields the
+        :class:`repro.serving.telemetry.SpanRecorder`. Off by default, and
+        then each span site costs one ``is None`` test.
+
+        Spans, on the ``time.time_ns`` clock: ``session.stage`` (caller's
+        thread: validate, int8 quantize, copy; one per ``submit`` /
+        ``submit_many`` / ``run_many`` call), then per device batch
+        ``session.admit`` (the dispatch side's coalescing hold, first
+        request taken to the cut), ``session.slot_wait`` (waiting for a
+        pipeline slot), ``session.assemble`` (the staging buffer),
+        ``session.launch`` (device put and executor enqueue),
+        ``session.sync`` (the drain side's wait for the device, plus int8
+        dequantize) and ``session.deliver`` (scattering rows to the
+        futures, their callbacks included)."""
+        if self._spans is not None:
+            raise RuntimeError("this session is already recording spans")
+        self._spans = rec = SpanRecorder()
+        try:
+            yield rec
+        finally:
+            self._spans = None
+
     def submit(self, x, *, deadline_ms: float | None = None) -> Future:
         """Enqueue one request; returns a Future of the result (a single
         item's logits for single-item requests, a batch for batched ones).
@@ -1367,10 +1428,9 @@ class ServingSession:
         result. When the session has a ``queue_limit`` and the queue is
         full, ``on_overload="shed"`` returns a future pre-failed with
         :class:`repro.serving.Overloaded`; ``"block"`` waits for space."""
-        now = time.monotonic()
-        req = self._make_request(x, Future(), now, deadline_ms)
-        self._enqueue([req])
-        return req.fut
+        reqs, stage_ns = self._stage_all([x], deadline_ms)
+        self._enqueue(reqs, stage_ns)
+        return reqs[0].fut
 
     def submit_many(self, xs, *, deadline_ms: float | None = None
                     ) -> list[Future]:
@@ -1381,10 +1441,8 @@ class ServingSession:
         traffic alone costs more than a device batch. Validation happens
         before anything enqueues, so a malformed request poisons nothing.
         """
-        now = time.monotonic()
-        reqs = [self._make_request(x, Future(), now, deadline_ms)
-                for x in xs]
-        self._enqueue(reqs)
+        reqs, stage_ns = self._stage_all(xs, deadline_ms)
+        self._enqueue(reqs, stage_ns)
         return [r.fut for r in reqs]
 
     def __call__(self, x):
@@ -1405,13 +1463,13 @@ class ServingSession:
         dispatch mutex serializes staging; the shared slot pool keeps
         device arbitration FIFO-fair), it just isn't co-batched with the
         bulk run."""
-        t0 = time.monotonic()
-        reqs = [self._make_request(x, None, t0, None) for x in xs]
+        reqs, stage_ns = self._stage_all(xs, None, fut=lambda: None)
         if not reqs:
             return []
         with self._cv:
             if self._closed:
                 raise RuntimeError("ServingSession is closed")
+            self.stats.stage_ns += stage_ns
         self.stats.bump("submitted", len(reqs))
         # cut [start, end) item groups of <= max_batch rows
         groups, start, n = [], 0, 0
@@ -1424,7 +1482,7 @@ class ServingSession:
         groups.append((start, len(reqs), n))
         out: list = [None] * len(reqs)
         errs: list[Exception] = []
-        inflight: deque = deque()   # (start, end, y, bucket, buf)
+        inflight: deque = deque()   # (start, end, y, bucket, buf, seq)
 
         def _deliver_bulk(s0, outcomes):
             st = self.stats
@@ -1441,13 +1499,13 @@ class ServingSession:
                 st.bump("errors")
 
         def _sync_oldest():
-            s0, e0, y, bucket, buf = inflight.popleft()
+            s0, e0, y, bucket, buf, seq = inflight.popleft()
             group = reqs[s0:e0]
             try:
                 if self._faults is not None:
                     self._faults.visit(
                         "drain", requests=[r.rid for r in group])
-                y_np = self._to_host(y)          # host sync (+ dequant)
+                y_np = self._sync(y, seq)        # host sync (+ dequant)
             except Exception as exc:  # noqa: BLE001 — recover per request
                 # recover BEFORE releasing the slot: the staging ring must
                 # not refill ``buf`` until the bisection has re-read it
@@ -1462,20 +1520,24 @@ class ServingSession:
             _deliver_bulk(
                 s0, [(r, True, y_np[r.off:r.off + r.x.shape[0]])
                      for r in group])
-            self.stats.record_latencies(
-                [(done_t - t0) * 1e3] * (e0 - s0))
+            st = self.stats
+            with st._lat_lock:
+                for r in group:
+                    st.latency_hist.add((done_t - r.t_submit) * 1e3)
 
         try:
             for s0, e0, n in groups:
                 if len(inflight) >= self._slots.capacity:
                     _sync_oldest()   # never self-deadlock on the pool
                 group = reqs[s0:e0]
+                seq = next(self._batch_seq)
                 self._slots.acquire()
                 bucket = buf = None
                 try:
                     with self._dispatch_mutex:
-                        bucket, buf = self._stage_group(group, n, bulk=True)
-                    y = self._launch(bucket, buf, group)
+                        bucket, buf = self._stage_group(group, n, seq,
+                                                        bulk=True)
+                    y = self._launch(bucket, buf, group, seq)
                 except Exception as e:  # noqa: BLE001 — recover per request
                     try:
                         if buf is None:
@@ -1488,7 +1550,7 @@ class ServingSession:
                 except BaseException:
                     self._slots.release()
                     raise
-                inflight.append((s0, e0, y, bucket, buf))
+                inflight.append((s0, e0, y, bucket, buf, seq))
         finally:
             while inflight:     # release EVERY held slot even on error
                 try:
@@ -1620,7 +1682,8 @@ class ServingSession:
         ``DeadlineExceeded``); and a retired generation (watchdog restart)
         hands its partial batch back to the queue and stands down.
 
-        Returns ``(group, n, stale)``.
+        Returns ``(group, n, stale, hold_ns)``, ``hold_ns`` the coalescing
+        hold from the first request taken to the cut (0 with spans off).
         """
         continuous = self.scheduler == "continuous"
         with self._cv:
@@ -1629,9 +1692,11 @@ class ServingSession:
                 self._beat("dispatch")
                 self._cv.wait(0.25)
             if self._gen != gen:
-                return None, 0, True
+                return None, 0, True, 0
             if not self._pending:
-                return None, 0, False    # closed and drained
+                return None, 0, False, 0    # closed and drained
+            # the hold is timed only for the session.admit span
+            t_hold = time.perf_counter_ns() if self._spans is not None else 0
             group, n = [], 0
             deadline = time.monotonic() + self._max_wait
             hard_deadline = deadline + 8 * self._max_wait
@@ -1665,8 +1730,9 @@ class ServingSession:
             if self._gen != gen:
                 # retired mid-take: hand the batch to the new pipeline
                 self._pending.extendleft(reversed(group))
-                return None, 0, True
-            return group, n, False
+                return None, 0, True, 0
+            return (group, n, False,
+                    time.perf_counter_ns() - t_hold if t_hold else 0)
 
     def _to_host(self, y) -> np.ndarray:
         """Host-sync one device batch; dequantize int8 logits to fp32.
@@ -1680,6 +1746,17 @@ class ServingSession:
             return (y_np.astype(np.float32)
                     * np.float32(self._quant.output_scale))
         return y_np
+
+    def _sync(self, y, seq: int) -> np.ndarray:
+        """``_to_host`` as the ``session.sync`` span of batch ``seq``."""
+        spans = self._spans
+        if spans is None:
+            return self._to_host(y)
+        t0 = time.perf_counter_ns()
+        try:
+            return self._to_host(y)
+        finally:
+            spans.add("session.sync", time.perf_counter_ns() - t0, seq)
 
     def _run_bucket(self, x):
         """Place one staged batch on its device(s) and run it. A bucket
@@ -1695,7 +1772,7 @@ class ServingSession:
             return entry(self._params, jnp.asarray(x))
         return self.acc(x)
 
-    def _stage_group(self, group, n, *, bulk: bool = False):
+    def _stage_group(self, group, n, seq: int, *, bulk: bool = False):
         """Assemble one device batch into the staging ring — no dispatch.
 
         Assembly is numpy into a preallocated staging ring (one buffer per
@@ -1706,6 +1783,7 @@ class ServingSession:
         failed batch can be bisected at the same offsets. Returns
         ``(bucket, buf)``; ``_launch`` dispatches it.
         """
+        t0 = time.perf_counter_ns()
         bucket = next(b for b in self.buckets if b >= n)
         if bulk:
             ring = self._staging_bulk.get(bucket)
@@ -1727,22 +1805,31 @@ class ServingSession:
             off += k
         if bucket > n:
             buf[n:] = 0
-            self.stats.padded_rows += bucket - n
-        self.stats.dispatched_rows += n
         now = time.monotonic()
-        self.stats.record_waits([(now - r.t_submit) * 1e3 for r in group])
         dev_ids = (self._fleet_device_ids
                    if bucket in self._sharded_entries
                    else self._local_device_ids)
-        for d in dev_ids:
-            self.stats.device_batches[d] = \
-                self.stats.device_batches.get(d, 0) + 1
+        st = self.stats
+        dt = time.perf_counter_ns() - t0
+        with st._lat_lock:
+            st.padded_rows += bucket - n
+            st.dispatched_rows += n
+            for r in group:
+                st.wait_hist.add((now - r.t_submit) * 1e3)
+            for d in dev_ids:
+                st.device_batches[d] = st.device_batches.get(d, 0) + 1
+            st.assemble_ns += dt
+        spans = self._spans
+        if spans is not None:
+            spans.add("session.assemble", dt, seq)
+            spans.batches[seq] = tuple(r.rid for r in group)
         return bucket, buf
 
-    def _launch(self, bucket, buf, group):
+    def _launch(self, bucket, buf, group, seq: int):
         """Launch a staged batch — no host sync. The fault harness's
         ``dispatch`` and ``execute`` sites fire here; the drain thread (or
         the bulk path) syncs the returned in-flight device result."""
+        t_launch = time.perf_counter_ns()
         if self._faults is not None:
             rids = [r.rid for r in group]
             self._faults.visit("dispatch", requests=rids)
@@ -1761,6 +1848,11 @@ class ServingSession:
             self._warm.add(bucket)
         else:
             y = self._run_bucket(buf)
+        dt = time.perf_counter_ns() - t_launch
+        self.stats.bump("launch_ns", dt)
+        spans = self._spans
+        if spans is not None:
+            spans.add("session.launch", dt, seq)
         return y
 
     # -- failure handling ---------------------------------------------------
@@ -1813,35 +1905,42 @@ class ServingSession:
             return False    # expired/cancelled first; already accounted
         return True
 
-    def _deliver(self, group, y_np):
+    def _deliver(self, group, y_np, seq: int):
         """Scatter a drained batch's rows to its futures + count it."""
+        t0 = time.perf_counter_ns()
         done_t = time.monotonic()
-        n_ok, lats = 0, []
+        lats = []
         for r in group:
             rows = y_np[r.off:r.off + r.x.shape[0]]
             if self._resolve_req(r, rows):
-                n_ok += 1
                 lats.append((done_t - r.t_submit) * 1e3)
+        dt = time.perf_counter_ns() - t0
+        spans = self._spans
+        if spans is not None:
+            spans.add("session.deliver", dt, seq)
         st = self.stats
-        st.bump("batches")
-        if n_ok:
-            st.bump("requests", n_ok)
-            st.record_latencies(lats)
+        with st._lat_lock:
+            st.batches += 1
+            st.requests += len(lats)
+            for ms in lats:
+                st.latency_hist.add(ms)
+            st.deliver_ns += dt
 
     def _deliver_outcomes(self, group, outcomes):
         """Resolve per-request recovery outcomes ``(req, ok, rows|exc)``."""
         done_t = time.monotonic()
-        n_ok, lats = 0, []
+        lats = []
         for r, ok, val in outcomes:
             if ok:
                 if self._resolve_req(r, val):
-                    n_ok += 1
                     lats.append((done_t - r.t_submit) * 1e3)
             else:
                 self._reject_req(r, val)
-        if n_ok:
-            self.stats.bump("requests", n_ok)
-            self.stats.record_latencies(lats)
+        st = self.stats
+        with st._lat_lock:
+            st.requests += len(lats)
+            for ms in lats:
+                st.latency_hist.add(ms)
 
     def _fallback_entry(self, bucket: int):
         """The lazily-compiled XLA degradation executor for ``bucket`` —
@@ -1960,7 +2059,7 @@ class ServingSession:
         without touching shared pipeline state."""
         try:
             while True:
-                group, n, stale = self._take_group(gen)
+                group, n, stale, hold_ns = self._take_group(gen)
                 if stale:
                     return
                 if group is None:
@@ -1975,13 +2074,22 @@ class ServingSession:
                 # watchdog can fail its futures if we die before handoff
                 self._worker_group = group
                 self._beat("dispatch")
+                seq = next(self._batch_seq)
+                spans = self._spans
+                if spans is not None:
+                    spans.add("session.admit", hold_ns, seq)
+                t_wait = time.perf_counter_ns() if spans is not None else 0
                 # acquire the pipeline slot BEFORE launching, so at most
                 # pool-capacity device batches are ever outstanding — across
                 # the whole Fleet when the pool is shared. The wait is
                 # cancellable on generation retirement: a wedged pool (its
                 # holder crashed) must not block the watchdog restart.
-                if not self._slots.acquire(
-                        cancelled=lambda: self._gen != gen):
+                acquired = self._slots.acquire(
+                    cancelled=lambda: self._gen != gen)
+                if spans is not None:
+                    spans.add("session.slot_wait",
+                              time.perf_counter_ns() - t_wait, seq)
+                if not acquired:
                     with self._cv:
                         self._pending.extendleft(reversed(group))
                     self._worker_group = None
@@ -1990,8 +2098,8 @@ class ServingSession:
                 bucket = buf = None
                 try:
                     with self._dispatch_mutex:
-                        bucket, buf = self._stage_group(group, n)
-                    y = self._launch(bucket, buf, group)
+                        bucket, buf = self._stage_group(group, n, seq)
+                    y = self._launch(bucket, buf, group, seq)
                 except Exception as e:  # noqa: BLE001 — recover per request
                     try:
                         outcomes = (self._recover(group, bucket, buf, e)
@@ -2010,7 +2118,7 @@ class ServingSession:
                     if self._gen != gen:
                         retired = True    # watchdog owns cleanup now
                     else:
-                        self._inflight.append((group, y, bucket, buf))
+                        self._inflight.append((group, y, bucket, buf, seq))
                         self._worker_holds_slot = False
                         self._worker_group = None
                         self._inflight_cv.notify_all()
@@ -2052,14 +2160,14 @@ class ServingSession:
                 if item is None:
                     return
                 self._beat("drain")
-                group, y, bucket, buf = item
+                group, y, bucket, buf, seq = item
                 exc = None
                 try:
                     if self._faults is not None:
                         self._faults.visit(
                             "drain", requests=[r.rid for r in group])
-                    y_np = self._to_host(y)  # the one host sync per batch
-                                             # (+ dequant for int8 sessions)
+                    y_np = self._sync(y, seq)  # the one host sync per batch
+                                               # (+ dequant for int8)
                 except Exception as e:  # noqa: BLE001 — device error lands here
                     exc = e
                 outcomes = (None if exc is None
@@ -2076,7 +2184,7 @@ class ServingSession:
                 if outcomes is not None:
                     self._deliver_outcomes(group, outcomes)
                 else:
-                    self._deliver(group, y_np)
+                    self._deliver(group, y_np, seq)
                 self._drain_group = None
         except BaseException as e:  # noqa: BLE001 — watchdog handles it
             self._thread_exc = e

@@ -776,6 +776,15 @@ def to_dram_params(program: Program, params: list) -> list:
     return out
 
 
+def layer_scope(cl: CompiledLayer) -> str:
+    """The ``jax.named_scope`` of one ISA layer's ops: ``L{layer_id}:{kind}``,
+    with ``conv.spat``/``conv.wino`` naming a CONV layer's PE mode. A
+    profiler trace carries it in each op's metadata, so device time can be
+    put down to the layer. Metadata only: it changes no optimized HLO."""
+    kind = f"conv.{cl.plan.mode}" if cl.kind == "conv" else cl.kind
+    return f"L{cl.layer_id}:{kind}"
+
+
 def lower_program(program: Program, *, backend: str = "xla",
                   interpret: bool | None = None, opt_level: int = 1,
                   quant: QuantSidecar | None = None
@@ -841,50 +850,59 @@ def lower_program(program: Program, *, backend: str = "xla",
         cl0 = program.layers[0]
         x = x_nhwc
         if cl0.inp_layout == "wino":
-            x = layouts.save_transform(x, "wino", cl0.plan.m)
+            # the input's SAVE-side reorder is the first layer's work
+            with jax.named_scope(layer_scope(cl0)):
+                x = layouts.save_transform(x, "wino", cl0.plan.m)
         stash: dict[int, jax.Array] = {-1: x}   # produced, still-live fmaps
         pi = 0
         y = x
         for cl in program.layers:
-            x_in = stash[cl.primary_src()]
-            lq = quant.layers[cl.layer_id] if quant is not None else None
-            relu00 = relu_bits.get((cl.layer_id, 0, 0), cl.spec.relu) \
-                if cl.kind != "pool" else False
-            if cl.kind == "pool":
-                window, stride = pool_cfg.get(
-                    cl.layer_id, (cl.spec.window, cl.spec.stride))
-                y = pool_forward(cl, x_in, window, stride)
-            elif cl.kind == "eltwise":
-                y = eltwise_forward(cl, x_in, stash[cl.skip_src], relu00,
-                                    quant=lq)
-            elif cl.kind == "fc":
-                w_eff, b = params[pi]
-                pi += 1
-                y = fc_forward(cl, w_eff, b, x_in, relu00,
-                               backend=backend, interpret=interpret,
-                               quant=lq)
-            elif cl.kind == "dw":
-                w_eff, b = params[pi]
-                pi += 1
-                y = depthwise_forward(cl, w_eff, b, x_in, relu00, quant=lq)
-            else:
-                w_eff, b = params[pi]
-                pi += 1
-                y = _layer_forward(
-                    cl, w_eff, b, x_in,
-                    lambda ih, kg, cl=cl: relu_bits.get((cl.layer_id, ih, kg),
-                                                        cl.spec.relu),
-                    backend=backend, interpret=interpret,
-                    lowering=lowerings.get(cl.layer_id), quant=lq)
-            # _layer_forward applies the SAVE-side layout reorder itself;
-            # the single-dispatch kinds store what the consumer's LOAD wants
-            if cl.kind != "conv" and cl.out_layout == "wino":
-                y = layouts.save_transform(y, "wino", cl.out_m)
+            with jax.named_scope(layer_scope(cl)):
+                y, pi = _lower_layer(cl, params, pi, stash)
             stash[cl.layer_id] = y
             for src in list(stash):
                 if last_use.get(src, -2) <= cl.layer_id and src != cl.layer_id:
                     del stash[src]
         return y
+
+    def _lower_layer(cl, params, pi, stash):
+        """One layer's ops, its SAVE-side reorder included; returns the
+        stored output and the next param index."""
+        x_in = stash[cl.primary_src()]
+        lq = quant.layers[cl.layer_id] if quant is not None else None
+        relu00 = relu_bits.get((cl.layer_id, 0, 0), cl.spec.relu) \
+            if cl.kind != "pool" else False
+        if cl.kind == "pool":
+            window, stride = pool_cfg.get(
+                cl.layer_id, (cl.spec.window, cl.spec.stride))
+            y = pool_forward(cl, x_in, window, stride)
+        elif cl.kind == "eltwise":
+            y = eltwise_forward(cl, x_in, stash[cl.skip_src], relu00,
+                                quant=lq)
+        elif cl.kind == "fc":
+            w_eff, b = params[pi]
+            pi += 1
+            y = fc_forward(cl, w_eff, b, x_in, relu00,
+                           backend=backend, interpret=interpret,
+                           quant=lq)
+        elif cl.kind == "dw":
+            w_eff, b = params[pi]
+            pi += 1
+            y = depthwise_forward(cl, w_eff, b, x_in, relu00, quant=lq)
+        else:
+            w_eff, b = params[pi]
+            pi += 1
+            y = _layer_forward(
+                cl, w_eff, b, x_in,
+                lambda ih, kg, cl=cl: relu_bits.get((cl.layer_id, ih, kg),
+                                                    cl.spec.relu),
+                backend=backend, interpret=interpret,
+                lowering=lowerings.get(cl.layer_id), quant=lq)
+        # _layer_forward applies the SAVE-side layout reorder itself;
+        # the single-dispatch kinds store what the consumer's LOAD wants
+        if cl.kind != "conv" and cl.out_layout == "wino":
+            y = layouts.save_transform(y, "wino", cl.out_m)
+        return y, pi
 
     return execute
 

@@ -202,11 +202,13 @@ def serve_cnn(arch: str = "vgg16", *, reduced: bool = True, batch: int = 8,
                   f"requests in {dt * 1e3:.1f}ms "
                   f"({n_req / dt:.1f} req/s, {st.batches} device "
                   f"batches, {st.padded_rows} padded rows, "
-                  f"occupancy {st.occupancy():.3f}; "
+                  f"occupancy {st.occupancy():.3f}; whole session: "
                   f"latency p50 {st.p50_ms():.2f}ms "
-                  f"p95 {st.p95_ms():.2f}ms; "
+                  f"p95 {st.p95_ms():.2f}ms, "
                   f"queue wait p50 {st.wait_p50_ms():.2f}ms "
-                  f"p95 {st.wait_p95_ms():.2f}ms; "
+                  f"p95 {st.wait_p95_ms():.2f}ms, "
+                  f"staging {st.stage_ns / 1e3 / max(st.submitted, 1):.1f}"
+                  f"us/request; "
                   f"compile {st.compile_ms:.0f}ms "
                   f"warm-load {st.warm_load_ms:.0f}ms)")
             per_dev = ", ".join(f"{d}: {n}" for d, n in

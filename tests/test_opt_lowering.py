@@ -259,7 +259,7 @@ def test_session_pipeline_stats_and_opt_level_inheritance():
         np.testing.assert_allclose(np.asarray(outs[0]), y_direct[0],
                                    atol=1e-5, rtol=1e-5)
         assert s.stats.requests == 8 and s.stats.batches >= 2
-        assert len(s.stats.latencies_ms) == 8
+        assert s.stats.latency_hist.count == 8
         assert 0 < s.stats.p50_ms() <= s.stats.p95_ms()
     # opt_level=0 session serves the reference lowering from its own entry
     with acc0.serve(max_batch=4, buckets=(4,), warmup=True) as s0:
