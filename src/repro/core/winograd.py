@@ -202,23 +202,46 @@ def winograd_apply_pretransformed(
 
     This is the runtime's COMP path: the paper stores *transformed* weights in
     DRAM (Sec. 4.2.3), so the PE consumes U directly.
+
+    No gather: the padded input is split into its ``m x m`` phase planes
+    ``x[:, a::m, b::m]`` by one reshape and a transpose of whole planes. A
+    tile's first ``m`` rows are its own tile's phases and its last ``PT - m
+    = r - 1 <= m`` the next tile's first phases, so each tile axis takes two
+    unit-stride slices of the planes and one concatenate. The planes keep
+    the batch beside the channels, so tiles flatten to ``(PT^2, nh nw N,
+    C)`` without moving data. ``B^T d B`` and ``A^T M A`` are each one
+    contraction over the ``PT^2`` tile axis with ``B^T (x) B^T`` and ``A^T
+    (x) A^T``, at the GEMM's precision. A strided index ``x[:, p::m]`` would
+    lower to a gather in JAX 0.9, which the TPU runs as a loop of dynamic
+    slices; a stack of ``PT^2`` single slices to a chain of in-place
+    updates that XLA leaves without the layer's ``op_name``.
+    ``tile_input``/``transform_input``/``transform_output`` are the same
+    algorithm in gather/einsum form, kept as the reference.
     """
     out_dtype = out_dtype or x_nhwc.dtype
     n, h, w, c = x_nhwc.shape
     pt, _, _, k = u_ptck.shape
     assert pt == pt_for(m), (pt, m)
     rr = R_WINO
-    if padding.upper() == "SAME":
-        ph = (rr - 1) // 2
-        x = jnp.pad(x_nhwc, ((0, 0), (ph, rr - 1 - ph), (ph, rr - 1 - ph), (0, 0)))
-    else:
-        x = x_nhwc
-    ho, wo = x.shape[1] - rr + 1, x.shape[2] - rr + 1
-    tiles, (nh, nw) = tile_input(x, m)
-    v = transform_input(tiles, m)                              # (PT^2, T, C)
+    lo = (rr - 1) // 2 if padding.upper() == "SAME" else 0
+    ho, wo = h + 2 * lo - rr + 1, w + 2 * lo - rr + 1
+    nh, nw = -(-ho // m), -(-wo // m)
+    # one pad: the conv's own, then out to whole (tile, phase) grids
+    x = jnp.pad(x_nhwc.astype(jnp.float32),
+                ((0, 0), (lo, (nh + 1) * m - h - lo),
+                 (lo, (nw + 1) * m - w - lo), (0, 0)))
+    planes = x.reshape(n, nh + 1, m, nw + 1, m, c).transpose(2, 4, 1, 3, 0, 5)
+    # (PT, m, nh, nw + 1, N, C), then (PT, PT, nh, nw, N, C)
+    rows = jnp.concatenate([planes[:, :, :nh], planes[:pt - m, :, 1:]], axis=0)
+    tiles = jnp.concatenate([rows[:, :, :, :nw], rows[:, :pt - m, :, 1:]], axis=1)
+    bt, _, at = transform_matrices(m, jnp.float32)
+    v = jnp.einsum("ap,ptc->atc", np.kron(bt, bt),
+                   tiles.reshape(pt * pt, nh * nw * n, c))
     u = u_ptck.astype(jnp.float32).reshape(pt * pt, c, k)
-    mm = jnp.einsum("ptc,pck->ptk", v, u)
-    y = transform_output(mm, m, n, nh, nw)[:, :ho, :wo, :]
+    mm = jnp.einsum("ptc,pck->ptk", v, u)                    # the PT^2 GEMMs
+    y = jnp.einsum("ap,ptk->atk", np.kron(at, at), mm)       # (m^2, nh nw N, K)
+    y = y.reshape(m, m, nh, nw, n, k).transpose(4, 2, 0, 3, 1, 5)
+    y = y.reshape(n, nh * m, nw * m, k)[:, :ho, :wo]
     if bias is not None:
         y = y + bias.astype(jnp.float32)
     if relu:

@@ -1,4 +1,5 @@
-"""Every Pallas kernel on the serving path compiles for a TPU v5e.
+"""Every Pallas kernel on the serving path compiles for a TPU v5e, and the
+XLA Winograd PE compiles there to ops that all keep their layer's scope.
 
 Each case compiles one kernel with ``interpret=False`` at VGG16's published
 widths (batch 8) for a chip that is described, not attached: the TPU
@@ -11,12 +12,14 @@ one process may load the TPU library, and the test workers all import this
 file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.winograd import winograd_apply_pretransformed
 from repro.kernels.gemm import matmul
 from repro.kernels.gemm.int8 import quantized_matmul
 from repro.kernels.gemm.kernel import batched_matmul_kernel
@@ -93,3 +96,33 @@ def test_kernel_compiles_for_v5e(name, one_chip):
             for shape, dtype in operands]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# VGG16 conv1_2 (224 + halo, C = K = 64) and conv4_1 (28 + halo, 256 -> 512)
+# on the XLA Winograd PE, F(4,3), batch 8
+WINO_SHAPES = [(226, 64, 64), (30, 256, 512)]
+_INSTR = re.compile(r"\s*(?:ROOT )?%?([\w.-]+) = .*? ([a-z][\w-]*)\(")
+
+
+@pytest.mark.parametrize("h,c,k", WINO_SHAPES)
+def test_xla_winograd_pe_keeps_its_scope_on_v5e(h, c, k, one_chip):
+    """Every fusion, dot, convolution and loop the TPU compiler makes of the
+    PE carries the layer's ``named_scope``, so a device trace can charge its
+    time to the layer (a stack of the tile slices compiled to a chain of
+    in-place updates with no ``op_name``); and no gather or loop."""
+    def layer(x, u, b):
+        with jax.named_scope("L1:conv.wino"):
+            return winograd_apply_pretransformed(x, u, b, 4, relu=True,
+                                                 padding="VALID")
+
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in ((N, h, h, c), (6, 6, c, k), (k,))]
+    text = jax.jit(layer).lower(*args).compile().as_text()
+    ops = [(m.group(1), m.group(2), line)
+           for line in text[text.index("\nENTRY"):].splitlines()
+           if (m := _INSTR.match(line))]
+    assert not [name for name, op, _ in ops if op in ("gather", "while")]
+    unscoped = [name for name, op, line in ops
+                if op in ("fusion", "dot", "convolution")
+                and "L1:conv.wino" not in line]
+    assert not unscoped
