@@ -18,11 +18,12 @@ import os
 import sys
 import time
 import types
+from collections.abc import Callable
 from pathlib import Path
 
 import numpy as np
 
-from bench import correct, registry
+from bench import correct, registry, scopes
 from bench import trace as tracing
 from bench.peaks import peaks_for
 
@@ -192,29 +193,107 @@ class GcWatch:
         gc.callbacks.remove(self)
 
 
+@dataclasses.dataclass
+class Scoped:
+    """What a traced window reads the program's layer scopes with: each HLO
+    instruction's scope (``names``), the scopes whose weights XLA prefetches
+    across programs (``prefetched``), and ``costs(rows)``: the reference's
+    ``layer_costs`` of a call on ``rows`` images, held to the program's
+    layers (``None`` for a reference that gives none)."""
+    names: dict
+    prefetched: set
+    costs: Callable[[int], list] | None
+
+
+def scoping(jax, served: Served, cfg: dict) -> Scoped | None:
+    """The layer scopes of the session's executor, from its compiled text
+    (``scopes.executor_hlo``), or ``None`` for a session of several
+    buckets: each bucket's module numbers its instructions its own way, and
+    the trace does not say in which module an op ran. A reference whose
+    ``layer_costs`` are not the program's layers fails here, before the
+    traced window."""
+    if len(served.session.buckets) != 1:
+        return None
+    hlo = scopes.executor_hlo(jax, served.session)
+    names = scopes.hlo_scopes(hlo)
+    costs = None
+    layer_costs = getattr(served.model, "layer_costs", None)
+    if layer_costs is not None:
+        program = [(cl.layer_id, cl.kind) for cl in served.acc.program.layers]
+        sizes = served.sizes
+        nbytes = np.dtype(cfg.get("build", {}).get("dtype", "float32")
+                          ).itemsize
+
+        def costs(rows):
+            return scopes.check_costs(layer_costs(sizes, rows, nbytes),
+                                      program)
+        costs(served.batch)
+    return Scoped(names, scopes.prefetched_scopes(hlo, names), costs)
+
+
 def window(jax, served: Served, mix: dict, seconds: float, seed: int,
-           trace: bool = False, rate: float | None = None):
-    """One window of the mix, traced or not: ``(log, counter deltas over the
-    window, reduced trace or None)``. Every request is then waited for, at
-    most a minute past the window's close."""
-    before = counters(served.session.stats)
-    reduced = None
+           trace: bool = False, rate: float | None = None,
+           scoped: Scoped | None = None):
+    """One window of the mix, traced or not: ``(log, the session's
+    statistics over the window (a ``SessionStats`` difference), the trace's
+    events or None)``. A traced window keeps each op's scope from
+    ``scoped``, and leaves the session's own spans off: they are host work
+    on the session's threads, and in a cell the host bounds they would move
+    ``idle_share`` (``bench/layer_profile.py`` measures them). Every
+    request is then waited for, at most a minute past the window's
+    close."""
+    before = served.session.stats.snapshot()
+    events = None
     with GcWatch() as gcw:
         if trace:
-            with tracing.capture() as events:
+            with scopes.capture(scoped.names if scoped else {}) as events:
                 log = drive(served, mix, seconds, seed, spans=events["host"],
                             rate=rate)
-            reduced = tracing.reduce(events, [d.id for d in served.devices])
         else:
             log = drive(served, mix, seconds, seed, rate=rate)
-    after = counters(served.session.stats)
+    stats = served.session.stats.snapshot() - before
     log.settle(60.0)
     done = np.sort([d for d in log.done if d is not None])
     done = done[done <= log.t_end]
     stall = float(np.max(np.diff(done))) * 1e3 if len(done) > 1 else None
     say(f"window of {seconds} s: {gcw.n} collections, longest "
         f"{gcw.longest_ms:.1f} ms; longest gap between answers {stall} ms")
-    return log, {k: after[k] - before[k] for k in COUNTERS}, reduced
+    return log, stats, events
+
+
+def reduce_trace(events: dict, device_ids, scoped: Scoped | None, stats,
+                 peak_ops: float, hbm_bytes_per_s: float) -> dict | None:
+    """A traced window's device numbers, or ``None`` where the trace holds
+    no window or no device events: ``trace.reduce``'s (``window_s``,
+    ``busy_s``, ``module_s``, ``idle_gaps``), ``device_ops`` labelled with
+    their scopes, ``scope_s`` and ``scoped_share`` (``scopes.reduce_scopes``)
+    and ``layers``: ``scopes.layer_rows`` per scope over the window's
+    executor calls (``stats``), or ``None`` without ``scope_s``, costs or a
+    call."""
+    reduced = scopes.reduce_scopes(events, device_ids)
+    if reduced is None:
+        return None
+    reduced["layers"] = None
+    if scoped and scoped.costs and reduced["scope_s"] and stats.batches:
+        rows = round((stats.dispatched_rows + stats.padded_rows)
+                     / stats.batches)
+        reduced["layers"] = scopes.layer_rows(
+            reduced["scope_s"], stats.batches, scoped.costs(rows), peak_ops,
+            hbm_bytes_per_s, scoped.prefetched)
+    return reduced
+
+
+def say_layers(reduced: dict) -> None:
+    """The traced window's table per ISA layer, on standard error."""
+    say(f"traced window: {100 * reduced['scoped_share']:.2f}% of op time "
+        f"scoped")
+    for row in reduced["layers"] or ():
+        share = ("-" if row["roofline"] is None
+                 else f"{row['roofline']:.1f}%")
+        say(f"  {row['scope']:<16} {row['ms_per_call']:9.4f} ms/call  "
+            f"roofline {share} ({row['bound']})"
+            + (f"  not measured: {row['unmeasured']}" if row["unmeasured"]
+               else ""))
 
 
 def memory_peak(devices) -> int | None:
@@ -227,7 +306,17 @@ def memory_peak(devices) -> int | None:
 def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
              t_process: float, root=registry.ROOT, base=registry.BENCH_DIR,
              device_check=check_chip) -> dict:
-    """One run of the cell ``name``; returns the result line's object."""
+    """One run of the cell ``name``; returns the result line's object.
+
+    Each metric's ``read(ctx)`` gets: ``seconds``, ``setup_s``, ``chips``,
+    ``config``, ``traffic``; the measured window's ``log``
+    (``loadgen.WindowLog``), ``latencies_ms``, ``completed_in_window``,
+    ``counters`` (``COUNTERS``' deltas) and ``stats`` (the session's
+    ``SessionStats`` difference over it: every counter, phase time and
+    histogram); ``flops_per_image``, ``peak_ops``; and, in a ``--trace 1``
+    run, ``trace``: ``reduce_trace``'s numbers with the traced window's
+    ``counters`` and ``stats`` (``None`` where the trace holds no device
+    events)."""
     bench = registry.load_benchmark(root)
     cell = registry.workload(bench, name)
     cfg = registry.load_config(cell["config"], base)
@@ -254,16 +343,24 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     say(f"set-up {setup_s:.3f} s; compile cache: {cache.hits} hits, "
         f"{cache.misses} misses")
 
-    log, delta, _ = window(jax, served, mix, seconds, seed)
+    log, stats, _ = window(jax, served, mix, seconds, seed)
+    delta = counters(stats)
     logs, reduced, traced = [log], None, None
     if trace:
         # the device is traced in a short window of its own after the
         # measured one: tracing slows the host, and a long trace is large
-        tlog, tdelta, reduced = window(jax, served, mix,
-                                       tracing.TRACE_SECONDS, seed + 2, True)
+        scoped = scoping(jax, served, cfg)
+        tlog, tstats, events = window(jax, served, mix, tracing.TRACE_SECONDS,
+                                      seed + 2, True, scoped=scoped)
         logs.append(tlog)
+        reduced = reduce_trace(events, [d.id for d in served.devices], scoped,
+                               tstats, peaks[cfg["compute"]],
+                               peaks["hbm_bytes_per_s"])
         if reduced:
-            traced = types.SimpleNamespace(counters=tdelta, **reduced)
+            if scoped:
+                say_layers(reduced)
+            traced = types.SimpleNamespace(counters=counters(tstats),
+                                           stats=tstats, **reduced)
     mem = memory_peak(served.devices)
     served.session.close()
     counts = {k: sum(lg.counts()[k] for lg in logs)
@@ -292,7 +389,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
 
     ctx = types.SimpleNamespace(
         seconds=seconds, setup_s=setup_s, chips=chips, config=cfg,
-        traffic=mix, log=log, counters=delta, trace=traced,
+        traffic=mix, log=log, counters=delta, stats=stats, trace=traced,
         completed_in_window=log.answered_by(log.t_end),
         latencies_ms=log.latencies_ms(),
         flops_per_image=model.flops_per_image(sizes),

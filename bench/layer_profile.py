@@ -11,9 +11,9 @@ with them on, the order turning each pair (off first in the first). Per
 window it prints the images answered per second, the latency percentiles,
 the idle share, the step's device ms and the session's host phases per
 image; for a window with spans on also the table per ISA layer (device ms
-per executor call, roofline share and its bound; ``scopes.py``), the ops
-labelled with their layer and the longest idle gaps, each named by the
-innermost open span, the session's included. At the end, per arm (spans
+per executor call, roofline share and its bound; ``harness.reduce_trace``),
+the ops labelled with their layer and the longest idle gaps, each named by
+the innermost open span, the session's included. At the end, per arm (spans
 off, spans on): the windows, how many stalled (no answer for over
 ``STALL_MS``), and the medians of answered images/s and p95 latency over
 every window and over those that did not stall. ``--out`` gets all of it
@@ -57,13 +57,7 @@ def main(argv=None) -> int:
     if mix.get("warm_seconds"):
         harness.drive(served, mix, mix["warm_seconds"],
                       args.seed + 1).settle(60.0)
-    hlo = scopes.executor_hlo(jax, served.session)
-    names = scopes.hlo_scopes(hlo)
-    prefetched = scopes.prefetched_scopes(hlo, names)
-    program_layers = [(cl.layer_id, cl.kind)
-                      for cl in served.acc.program.layers]
-    dtype_bytes = np.dtype(cfg.get("build", {}).get("dtype",
-                                                    "float32")).itemsize
+    scoped = harness.scoping(jax, served, cfg)
     ids = [d.id for d in served.devices]
     session = served.session
     windows, last_on = [], None
@@ -75,7 +69,8 @@ def main(argv=None) -> int:
         gc.freeze()
         before = session.stats.snapshot()
         with harness.GcWatch() as gcw, scopes.capture(
-                names, session if on else None) as events:
+                scoped.names if scoped else {},
+                session if on else None) as events:
             log = harness.drive(served, mix, tracing.TRACE_SECONDS,
                                 args.seed + 2 + i, spans=events["host"])
             win = session.stats.snapshot() - before
@@ -83,7 +78,9 @@ def main(argv=None) -> int:
         done = np.sort([d for d in log.done if d is not None])
         longest = float(np.max(np.diff(done))) * 1e3 if len(done) > 1 \
             else None
-        base = tracing.reduce(events, ids)
+        red = harness.reduce_trace(events, ids, scoped, win,
+                                   peaks[cfg["compute"]],
+                                   peaks["hbm_bytes_per_s"])
         lat = log.latencies_ms()
         rec = {
             "spans": on, "window": i,
@@ -105,24 +102,12 @@ def main(argv=None) -> int:
             "longest_gap_between_answers_ms": longest,
             "stalled": longest is None or longest > STALL_MS,
         }
-        if base:
+        if red:
             rec.update(
-                idle_share=100.0 * (1 - base["busy_s"] / base["window_s"]),
-                step_device_ms=base["module_s"] * 1e3 / max(win.batches, 1),
-                idle_gaps=base["idle_gaps"])
-        layered = scopes.reduce_scopes(events, ids) if base else None
-        if layered and win.batches:
-            rows = (win.dispatched_rows + win.padded_rows) / win.batches
-            costs = scopes.layer_costs(served.model, served.sizes,
-                                       round(rows), dtype_bytes,
-                                       program_layers)
-            rec.update(
-                scoped_share=layered["scoped_share"],
-                device_ops=layered["device_ops"],
-                layers=scopes.layer_rows(layered["scope_s"], win.batches,
-                                         costs, peaks[cfg["compute"]],
-                                         peaks["hbm_bytes_per_s"],
-                                         prefetched))
+                idle_share=100.0 * (1 - red["busy_s"] / red["window_s"]),
+                step_device_ms=red["module_s"] * 1e3 / max(win.batches, 1),
+                idle_gaps=red["idle_gaps"], scoped_share=red["scoped_share"],
+                device_ops=red["device_ops"], layers=red["layers"])
         if on:
             last_on = events
         windows.append(rec)
@@ -131,7 +116,8 @@ def main(argv=None) -> int:
     out = Path(args.out)
     out.parent.mkdir(parents=True, exist_ok=True)
     out.write_text(json.dumps({"workload": args.workload, "seed": args.seed,
-                               "prefetched": sorted(prefetched),
+                               "prefetched": sorted(scoped.prefetched)
+                               if scoped else None,
                                "windows": windows, "events": last_on}))
     for on in (False, True):
         sel = [w for w in windows if w["spans"] == on]

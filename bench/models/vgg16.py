@@ -103,3 +103,56 @@ def forward(params, x, sizes: dict, *, act=None, weight=None):
             if layer[3]:
                 y = jnp.maximum(y, 0.0)
     return y
+
+
+def layer_costs(sizes: dict, rows: int, dtype_bytes: int) -> list:
+    """Per ISA layer, in ``layers(sizes)``' order, ``(kind, flops, bytes)``
+    of one executor call on ``rows`` images: FLOPs of direct convolution and
+    the dense layers (2 per MAC; pooling counts none), bytes of weights and
+    biases once plus each image's input and output maps, all at
+    ``dtype_bytes``.
+
+    It describes VGG's layers alone: 3x3 stride-1 "same" convolutions,
+    2x2/2 max pools and dense layers. It raises on any other layer tuple,
+    on a weight ``init_params`` shapes otherwise than the tuple says, and
+    where a layer does not read the map the layer before it writes."""
+    shapes = jax.eval_shape(lambda k: init_params(k, sizes),
+                            jax.random.key(0))
+    out, pi, prev = [], 0, None     # prev: the map before, (h, c) or (d,)
+    for layer in layers(sizes):
+        kind = layer[0]
+        if kind == "conv" and len(layer) == 4:
+            _, h, c, k = layer
+            w = tuple(shapes[pi][0].shape)
+            pi += 1
+            if w != (3, 3, c, k):
+                raise ValueError(f"conv weight {w} is not 3x3 {c}->{k}")
+            flops = 2 * h * h * 9 * c * k
+            weights, maps = 9 * c * k + k, h * h * (c + k)
+            inp, nxt = (h, c), (h, k)
+        elif kind == "pool" and len(layer) == 3 and layer[1] % 2 == 0:
+            _, h, c = layer
+            flops, weights = 0, 0
+            maps = h * h * c + (h // 2) ** 2 * c
+            inp, nxt = (h, c), (h // 2, c)
+        elif kind == "fc" and len(layer) == 4:
+            _, d_in, d_out, _ = layer
+            w = tuple(shapes[pi][0].shape)
+            pi += 1
+            if w != (d_in, d_out):
+                raise ValueError(f"dense weight {w} is not {d_in}x{d_out}")
+            flops = 2 * d_in * d_out
+            weights, maps = d_in * d_out + d_out, d_in + d_out
+            inp, nxt = (d_in,), (d_out,)
+            if prev is not None and len(prev) == 2:
+                prev = (prev[0] * prev[0] * prev[1],)
+        else:
+            raise ValueError(f"no cost is described for layer {layer}")
+        if prev is not None and inp != prev:
+            raise ValueError(f"layer {layer} does not read the map {prev} "
+                             f"the layer before it writes")
+        prev = nxt
+        out.append((kind, rows * flops, dtype_bytes * (weights + rows * maps)))
+    if pi != len(shapes):
+        raise ValueError(f"{len(shapes)} weights for {pi} weighted layers")
+    return out

@@ -6,11 +6,21 @@
   or backlog, buckets, serving keywords, input pool, latency limit);
 - a loop: ``loops/<name>.py``, whose ``run(...)`` drives one window of a
   mix that names it (``loadgen.py`` holds what the loops share);
-- a plain model reference: ``models/<name>.py``;
+- a plain model reference: ``models/<name>.py``, which imports nothing of
+  the program and gives ``init_params(key, sizes)`` (the seeded weights, in
+  the layout the program is built with), ``forward(params, x, sizes, *,
+  act=None, weight=None)`` (logits at ``highest`` precision; ``act`` and
+  ``weight`` map each matmul's operands, for the controls),
+  ``flops_per_image(sizes)`` and, optionally, ``layer_costs(sizes, rows,
+  dtype_bytes)``: ``(kind, flops, bytes)`` of one executor call on ``rows``
+  images per ISA layer, in the program's order, which the traced run holds
+  to the program's layers and reads rooflines from (without it a cell's
+  ``layers`` table and its roofline metrics read ``None``);
 - a per-layer metric: ``metrics/<name>.py``, whose ``read(ctx)`` returns the
-  value or ``None`` where it finds nothing to read.
+  value or ``None`` where it finds nothing to read (``harness.run_cell``
+  says what ``ctx`` holds).
 
-A later cell, mix, loop or metric is new files plus new entries in
+A later cell, mix, loop, metric or model is new files plus new entries in
 ``BENCHMARK.json``; nothing here changes.
 """
 from __future__ import annotations

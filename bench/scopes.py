@@ -15,25 +15,27 @@ name (``executor_hlo``, ``hlo_scopes``). XLA's own instructions carry no
 metadata: a ``while`` that XLA made of a convolution has its layer's scope,
 its body's ops have none and take the scope of the op that encloses them in
 time; the layout copy of the input, named ``x``, belongs to the first
-layer; an async copy (``copy-start``/``copy-done``) belongs to the layer
-that reads what it copies.
+layer. Any other instruction of the entry computation that XLA made with
+no ``op_name`` (as the in-place update chain of a stacked tensor, or an
+async copy), which no op encloses in time, takes the scope of its nearest
+scoped operand, or failing that of its nearest scoped reader.
 
 - ``capture(names, session)``: ``trace.capture`` with the session's spans
   recorded too and every op's scope kept;
-- ``reduce_scopes(events, device_ids)``: per scope, the union of its op
-  intervals inside the window (so a ``while`` and the ops of its body count
-  once), the share of op time that carries a scope, and ``device_ops``
-  labelled with their scope (``while.1 [L2:conv.spat]``);
-- ``layer_costs`` and ``layer_rows``: per ISA layer, FLOPs (direct
-  convolution and the dense layers, 2 per MAC) and bytes (weights and
-  biases, input and output maps at the configuration's dtype) from the
-  reference's layer list, and per executor call the device ms and the
-  roofline share: the larger of FLOPs over peak and bytes over the HBM
-  rate, over the layer's time.
+- ``reduce_scopes(events, device_ids)``: ``trace.reduce``'s numbers, and per
+  scope the union of its op intervals inside the window (so a ``while`` and
+  the ops of its body count once), the share of op time that carries a
+  scope, and ``device_ops`` labelled with their scope (``while.1
+  [L2:conv.spat]``);
+- ``check_costs`` and ``layer_rows``: the model reference's ``layer_costs``
+  (FLOPs and bytes per ISA layer, ``bench/registry.py``) held to the
+  program's layers one for one, and per executor call the device ms and the
+  roofline share of each layer: the larger of FLOPs over peak and bytes over
+  the HBM rate, over the layer's time.
 
-The scoped reduction reads ``None`` where less than ``MIN_SCOPED`` of the
-op time in the window carries a scope: a program whose scopes went missing
-then reads as unmeasured, not as fast.
+The scoped reduction's ``scope_s`` reads ``None`` where less than
+``MIN_SCOPED`` of the op time in the window carries a scope: a program whose
+scopes went missing then reads as unmeasured, not as fast.
 """
 from __future__ import annotations
 
@@ -47,7 +49,6 @@ MIN_SCOPED = 0.95
 _HLO_LINE = re.compile(r"\s*(?:ROOT )?%?([\w.-]+) = (.*)$")
 _OP_NAME = re.compile(r'metadata=\{[^}]*op_name="([^"]*)"')
 _OPERAND = re.compile(r"%([\w.-]+)")
-_ASYNC_COPY = re.compile(r"\bcopy-(?:start|done)\(")
 _CROSS_PROGRAM = "cross_program_prefetch_index"
 
 
@@ -55,17 +56,27 @@ def hlo_scopes(hlo_text: str) -> dict[str, str]:
     """HLO instruction name -> layer scope, from a compiled module's text
     (``compiled.as_text()``). The copy XLA makes of the executor's input
     argument ``x`` (``op_name="x"``, its layout for the first layer) is the
-    first layer's load, and takes that layer's scope. An async copy
-    (``copy-start`` and its ``copy-done``, XLA's prefetch of a weight)
-    takes the scope of the first scoped op that reads the copied value."""
-    out, inputs, copies = {}, [], []
+    first layer's load, and takes that layer's scope. Any other instruction
+    with no ``op_name`` in the entry computation (in a text with no
+    computation headers, anywhere) takes the scope of its nearest scoped
+    operand, or failing that of its nearest scoped reader; one in a
+    ``while`` body is left to the op that encloses it in time. An async
+    copy of a weight (``copy-start`` and its ``copy-done``, XLA's prefetch)
+    reads only a parameter, which carries no scope, so it takes the scope
+    of the first scoped op that reads the copied value."""
+    out, inputs, bare = {}, [], []
+    operands: dict[str, list[str]] = {}
     users: dict[str, list[str]] = {}
+    entry = True
     for line in hlo_text.splitlines():
         m = _HLO_LINE.match(line)
         if not m:
+            if line.rstrip().endswith("{"):     # a computation's header
+                entry = line.lstrip().startswith("ENTRY")
             continue
         name, rest = m.groups()
-        for operand in _OPERAND.findall(rest.split(", metadata=")[0]):
+        operands[name] = _OPERAND.findall(rest.split(", metadata=")[0])
+        for operand in operands[name]:
             users.setdefault(operand, []).append(name)
         op = _OP_NAME.search(rest)
         s = SCOPE.search(op.group(1)) if op else None
@@ -73,26 +84,29 @@ def hlo_scopes(hlo_text: str) -> dict[str, str]:
             out[name] = s.group(1)
         elif op and op.group(1) == "x":
             inputs.append(name)
-        elif _ASYNC_COPY.search(rest):
-            copies.append(name)
+        elif op is None and entry:
+            bare.append(name)
     if out:
         first = min(out.values(), key=_layer_id)
         out.update(dict.fromkeys(inputs, first))
 
-    def reader_scope(name):
-        """The scope of the nearest scoped op that reads ``name``."""
+    def nearest(name, edges, scoped):
+        """The scope of the nearest instruction in ``scoped`` reached from
+        ``name`` along ``edges`` (operands or readers)."""
         todo, seen = [name], {name}
         while todo:
-            for u in users.get(todo.pop(0), ()):
-                if u in out:
-                    return out[u]
+            for u in edges.get(todo.pop(0), ()):
+                if u in scoped:
+                    return scoped[u]
                 if u not in seen:
                     seen.add(u)
                     todo.append(u)
         return None
-    for name in copies:
-        if (s := reader_scope(name)) is not None:
-            out[name] = s
+    for edges in (operands, users):
+        known = dict(out)
+        for name in bare:
+            if name not in out and (s := nearest(name, edges, known)):
+                out[name] = s
     return out
 
 
@@ -152,14 +166,14 @@ def capture(names: dict[str, str], session=None):
 
 
 def reduce_scopes(events: dict, device_ids) -> dict | None:
-    """Per-layer device numbers over the ``bench.window`` span, averaged
-    over ``device_ids``, or ``None`` where the trace holds no window, no
-    device events or too little scoped op time:
+    """``trace.reduce``'s numbers over the ``bench.window`` span, with the
+    per-layer ones beside them, averaged over ``device_ids``; ``None`` where
+    ``trace.reduce`` reads ``None`` (no window or no device events):
 
-    - ``scope_s``: per scope, the union of its op intervals;
+    - ``scope_s``: per scope, the union of its op intervals; ``None`` where
+      ``scoped_share`` is under ``MIN_SCOPED``;
     - ``scoped_share``: the union of the scoped op intervals over the union
-      of all op intervals (at least ``MIN_SCOPED``);
-    - ``module_s``: as ``trace.reduce``;
+      of all op intervals;
     - ``device_ops``: ``trace.reduce``'s, each name followed by the scope
       that holds most of its time.
     """
@@ -194,20 +208,18 @@ def reduce_scopes(events: dict, device_ids) -> dict | None:
         scoped += _length(covered)
         total += _length(every)
     share = scoped / total if total else 0.0
-    if share < MIN_SCOPED:
-        return None
     n = len(device_ids)
 
     def label(name):
         by = name_scope.get(name)
         return f"{name} [{max(by, key=by.get)}]" if by else name
-    return {
-        "scope_s": {s: t / n / 1e9 for s, t in sorted(
-            scope_t.items(), key=lambda kv: _layer_id(kv[0]))},
-        "scoped_share": share,
-        "module_s": base["module_s"],
-        "device_ops": [[label(name), t] for name, t in base["device_ops"]],
-    }
+    return dict(
+        base,
+        scope_s=None if share < MIN_SCOPED else {
+            s: t / n / 1e9 for s, t in sorted(
+                scope_t.items(), key=lambda kv: _layer_id(kv[0]))},
+        scoped_share=share,
+        device_ops=[[label(name), t] for name, t in base["device_ops"]])
 
 
 def _enclosed(ops, op_scopes) -> list:
@@ -235,76 +247,30 @@ def _layer_id(scope: str) -> int:
     return int(scope[1:scope.index(":")])
 
 
-def layer_costs(model, sizes: dict, rows: int, dtype_bytes: int,
-                program_layers) -> list:
-    """Per ISA layer of the reference (``model.layers(sizes)``), ``(kind,
-    flops, bytes)`` of one executor call on ``rows`` images: FLOPs of
-    direct convolution and the dense layers (2 per MAC; pooling counts
-    none), bytes of weights and biases once plus each image's input and
-    output maps, all at ``dtype_bytes``.
-
-    It describes VGG's layers alone: 3x3 stride-1 "same" convolutions,
-    2x2/2 max pools and dense layers. It raises on any other layer tuple,
-    kernel or shape, and where the program's layers (``program_layers``,
-    ``(layer_id, kind)`` of each ISA layer) are not the reference's, one
-    for one and numbered from 0 in order."""
-    import jax
-
-    layers = model.layers(sizes)
-    shapes = jax.eval_shape(lambda k: model.init_params(k, sizes),
-                            jax.random.key(0))
+def check_costs(costs: list, program_layers) -> list:
+    """``costs`` (a model reference's ``layer_costs``: ``(kind, flops,
+    bytes)`` per ISA layer) where they are the program's layers
+    (``program_layers``, ``(layer_id, kind)`` of each) one for one, numbered
+    from 0 in order; raises ``ValueError`` otherwise."""
     if [tuple(p) for p in program_layers] != [
-            (i, layer[0]) for i, layer in enumerate(layers)]:
+            (i, c[0]) for i, c in enumerate(costs)]:
         raise ValueError(f"the program's layers {list(program_layers)} are "
-                         f"not the reference's {[l[0] for l in layers]}")
-    out, pi, prev = [], 0, None     # prev: the map before, (h, c) or (d,)
-    for layer in layers:
-        kind = layer[0]
-        if kind == "conv" and len(layer) == 4:
-            _, h, c, k = layer
-            w = tuple(shapes[pi][0].shape)
-            pi += 1
-            if w != (3, 3, c, k):
-                raise ValueError(f"conv weight {w} is not 3x3 {c}->{k}")
-            flops = 2 * h * h * 9 * c * k
-            weights, maps = 9 * c * k + k, h * h * (c + k)
-            inp, nxt = (h, c), (h, k)
-        elif kind == "pool" and len(layer) == 3 and layer[1] % 2 == 0:
-            _, h, c = layer
-            flops, weights = 0, 0
-            maps = h * h * c + (h // 2) ** 2 * c
-            inp, nxt = (h, c), (h // 2, c)
-        elif kind == "fc" and len(layer) == 4:
-            _, d_in, d_out, _ = layer
-            w = tuple(shapes[pi][0].shape)
-            pi += 1
-            if w != (d_in, d_out):
-                raise ValueError(f"dense weight {w} is not {d_in}x{d_out}")
-            flops = 2 * d_in * d_out
-            weights, maps = d_in * d_out + d_out, d_in + d_out
-            inp, nxt = (d_in,), (d_out,)
-            if prev is not None and len(prev) == 2:
-                prev = (prev[0] * prev[0] * prev[1],)
-        else:
-            raise ValueError(f"no cost is described for layer {layer}")
-        if prev is not None and inp != prev:
-            raise ValueError(f"layer {layer} does not read the map {prev} "
-                             f"the layer before it writes")
-        prev = nxt
-        out.append((kind, rows * flops, dtype_bytes * (weights + rows * maps)))
-    if pi != len(shapes):
-        raise ValueError(f"{len(shapes)} weights for {pi} weighted layers")
-    return out
+                         f"not the reference's {[c[0] for c in costs]}")
+    return costs
 
 
 def layer_rows(scope_s: dict, calls: int, costs: list, peak_ops: float,
                hbm_bytes_per_s: float, prefetched=()) -> list[dict]:
-    """Per scope: device ms per executor call, and the roofline share with
-    the bound that sets it (``compute`` or ``memory``). The share reads
-    ``None``, with the reason in ``unmeasured``, for a layer in
-    ``prefetched`` (``prefetched_scopes``: its weights move outside its
-    time) and for a share over 100% (its bytes or FLOPs are worked in
-    another scope's ops, as a pool fused into the convolution before it)."""
+    """Per scope: device ms per executor call, the least ms the chip could
+    take for the layer's FLOPs and bytes (``least_ms``), and the roofline
+    share, ``least_ms`` over the layer's ms, with the bound that sets it
+    (``compute`` or ``memory``), and whether the layer is in ``prefetched``
+    (``prefetched_scopes``: its weights move outside its time). The share
+    reads ``None`` in the table, with the reason in ``unmeasured``, for a
+    prefetched layer and for a share over 100% (its bytes or FLOPs are
+    worked in another scope's ops, as a pool fused into the convolution
+    before it); ``least_ms`` and ``ms_per_call`` stay, so a metric that
+    sums them sees such a share."""
     rows = []
     for scope, seconds in scope_s.items():
         kind, flops, nbytes = costs[_layer_id(scope)]
@@ -318,7 +284,8 @@ def layer_rows(scope_s: dict, calls: int, costs: list, peak_ops: float,
             unmeasured = "work done in another scope's ops"
         rows.append({
             "scope": scope, "ms_per_call": t * 1e3,
+            "least_ms": max(t_flops, t_bytes) * 1e3,
             "roofline": None if unmeasured else share,
             "bound": "compute" if t_flops >= t_bytes else "memory",
-            "unmeasured": unmeasured})
+            "prefetched": scope in prefetched, "unmeasured": unmeasured})
     return rows

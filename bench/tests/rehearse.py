@@ -1,7 +1,8 @@
 """Drive the whole harness without a chip, for the tests: a temporary
 ``BENCHMARK.json`` names cells of the tiny configurations under
 ``tests/configs`` (never cells of the real benchmark), the look for a TPU is
-replaced, and a fault can be planted in the served path underneath.
+replaced, a fault can be planted in the served path underneath, and a device
+plane the CPU's profile lacks can be laid into the trace.
 
     python -m bench.tests.rehearse <cell> <chips> [fault]
 
@@ -29,8 +30,12 @@ CELLS = {
     "tiny.single-stream": ("tiny", "single-stream"),
     "tiny.server-bursty": ("tiny", "server-bursty"),
     "tiny.overload": ("tiny", "overload"),
+    # a second network, its configuration and reference test files alone
+    "tiny-resnet18.offline": ("tiny-resnet18", "offline-small"),
 }
-FAKE_PEAKS = {"bf16": 1e12, "int8": 2e12}   # arithmetic only, never reported
+# arithmetic only, never reported
+FAKE_PEAKS = {"bf16": 1e12, "int8": 2e12, "hbm_bytes_per_s": 1e11}
+CALLS = 10      # executor calls in a laid device plane
 
 
 def cpu_check(jax, chips):
@@ -60,6 +65,33 @@ def write_root(tmp: Path, chips: int) -> Path:
             bench[group].append(m)
     (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
     return tmp
+
+
+def with_device_plane(attach):
+    """``scopes.attach_scopes`` that first lays, where the trace has no
+    device, one in the window as a TPU's would read: ``CALLS`` executor
+    calls, each a module in the first half of its share of the window
+    holding one op per scoped instruction of ``names``, in layer order. It
+    stands in for the device events only, so the scoped reduction, the
+    reference's ``layer_costs`` and the metrics run as on a chip."""
+    from bench import scopes
+    from bench import trace as tracing
+
+    def attach_with_plane(events, names):
+        window = [h for h in events["host"] if h[0] == tracing.WINDOW_SPAN]
+        if names and window and not events["devices"]:
+            _, w0, wdur = window[0]
+            order = sorted(names, key=lambda n: scopes._layer_id(names[n]))
+            share = wdur // CALLS
+            op = share // 2 // len(order)
+            ops, modules = [], []
+            for k in range(CALLS):
+                t = w0 + k * share
+                modules.append(["jit_traced", t, op * len(order)])
+                ops += [[n, t + i * op, op] for i, n in enumerate(order)]
+            events["devices"]["0"] = {"ops": ops, "modules": modules}
+        attach(events, names)
+    return attach_with_plane
 
 
 def plant(fault: str | None):

@@ -30,6 +30,7 @@ def _check_shape(out, trace):
     ("tiny-int8.offline", {"images_per_s", "setup_s"}),
     ("tiny.single-stream", {"images_per_s", "setup_s"}),
     ("tiny.server-bursty", {"latency_p95_ms", "latency_p50_ms", "setup_s"}),
+    ("tiny-resnet18.offline", {"images_per_s", "setup_s"}),
 ])
 def test_sound_run_is_correct(cell, metrics):
     out = rehearse.run(cell)
@@ -45,11 +46,50 @@ def test_traced_server_run_reads_host_metrics():
     assert out["correct"] is True
     # the CPU trace has no TPU plane: the device metrics find nothing
     assert set(out["metrics"]) == {"gen_lag_p95_ms.server",
-                                   "batch_occupancy.server"}
+                                   "batch_occupancy.server",
+                                   "queue_wait_p95_ms.server"}
     assert 0 < out["metrics"]["batch_occupancy.server"]["value"] <= 100
+    assert out["metrics"]["queue_wait_p95_ms.server"]["value"] > 0
 
 
-@pytest.mark.parametrize("cell", ["tiny.offline", "tiny.server"])
+@pytest.mark.parametrize("cell,kinds", [
+    ("tiny.offline", {"conv", "pool", "fc"}),
+    # a second network: its configuration and reference are test files, and
+    # the harness takes its layer costs as it takes VGG16's
+    ("tiny-resnet18.offline", {"conv", "pool", "eltwise", "fc"}),
+])
+def test_traced_run_reads_the_layer_metrics(cell, kinds, monkeypatch,
+                                            capsys):
+    """With a device plane laid into the CPU's trace, a traced run reads
+    every op's layer scope from the executor's compiled text, holds the
+    reference's layer costs to the program's layers and reports the
+    per-layer metrics of its layers."""
+    from bench import scopes
+    monkeypatch.setattr(scopes, "attach_scopes",
+                        rehearse.with_device_plane(scopes.attach_scopes))
+    out = rehearse.run(cell, trace=True)
+    _check_shape(out, True)
+    assert out["correct"] is True, out["checks"]
+    m = {k: v["value"] for k, v in out["metrics"].items()}
+    assert {"step_device_ms", "step_mfu", "idle_share", "conv_spat_step_ms",
+            "conv_spat_roofline", "stage_us_per_image"} <= set(m)
+    assert 0 < m["conv_spat_roofline"] <= 100
+    assert (m["conv_spat_step_ms"] + m.get("conv_wino_step_ms", 0)
+            <= m["step_device_ms"] * (1 + 1e-9))
+    assert m["stage_us_per_image"] > 0
+    assert all(" [L" in name for name, _ in out["breakdown"]["device_ops"])
+    # the layer table on standard error: a row per layer, of each kind
+    err = capsys.readouterr().err
+    assert "100.00% of op time scoped" in err
+    rows = [line.split()[0] for line in err.splitlines()
+            if line.startswith("  L")]
+    assert {r.split(":")[1].split(".")[0] for r in rows} == kinds
+    assert sorted(int(r[1:r.index(":")]) for r in rows) == list(
+        range(len(rows)))
+
+
+@pytest.mark.parametrize("cell", ["tiny.offline", "tiny.server",
+                                  "tiny-resnet18.offline"])
 @pytest.mark.parametrize("fault", ["answer", "half_batch"])
 def test_broken_path_is_not_correct(cell, fault, monkeypatch):
     from repro import api

@@ -11,7 +11,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import scopes
+from bench import harness, scopes
 from bench import trace as tracing
 from bench.models import vgg16
 from repro.serving.telemetry import SpanRecorder
@@ -65,16 +65,29 @@ def test_reduce_scopes_by_hand():
 
 
 def test_reduce_scopes_reads_none_when_scopes_are_missing():
+    base = tracing.reduce(_events(), [0])
+
+    def reduced(ev):
+        r = scopes.reduce_scopes(ev, [0])
+        # trace.reduce's numbers stand whatever the scopes read
+        assert {k: r[k] for k in base if k != "device_ops"} == {
+            k: v for k, v in base.items() if k != "device_ops"}
+        assert r["scope_s"] is None
+        return r
     ev = _events()
     ev["devices"]["0"]["op_scopes"] = [None] * 6
-    assert scopes.reduce_scopes(ev, [0]) is None
+    assert reduced(ev)["scoped_share"] == 0.0
     del ev["devices"]["0"]["op_scopes"]
-    assert scopes.reduce_scopes(ev, [0]) is None
+    assert reduced(ev)["device_ops"] == base["device_ops"]
     ev = _events()
     ev["devices"]["0"]["op_scopes"][3] = None     # 50 of 84 ns scoped
-    assert scopes.reduce_scopes(ev, [0]) is None
+    assert reduced(ev)["scoped_share"] == pytest.approx(50 / 84)
     ev = _events()
     ev["devices"]["0"]["op_scopes"][0] = None     # the while loses its
+    reduced(ev)
+    # no window, no device events: nothing to read at all
+    ev = _events()
+    ev["host"] = ev["host"][1:]
     assert scopes.reduce_scopes(ev, [0]) is None
 
 
@@ -86,9 +99,11 @@ def test_scopes_from_the_compiled_text():
             '  %fusion.8 = f32[8]{0} fusion(%copy), kind=kLoop, '
             'metadata={op_type="add" op_name="jit(traced)/L1:fc/add"}\n'
             '  ROOT %copy.2 = f32[8]{0} copy(%fusion.7)\n')
-    # the input's layout copy is the first layer's (here L1) load
+    # the input's layout copy is the first layer's (here L1) load; an op
+    # with no op_name takes its operand's scope
     assert scopes.hlo_scopes(text) == {"fusion.7": "L3:pool",
-                                       "fusion.8": "L1:fc", "copy": "L1:fc"}
+                                       "fusion.8": "L1:fc", "copy": "L1:fc",
+                                       "copy.2": "L3:pool"}
 
 
 # as a TPU v5e compile of the VGG16 executor prints them: fc7's weights
@@ -114,9 +129,50 @@ def test_async_copies_take_the_scope_of_their_reader():
     assert names["copy-start.5"] == names["copy-done.5"] == "L0:conv.spat"
     # read through a tuple by the while that holds its layer's scope
     assert names["copy-start.7"] == names["copy-done.7"] == "L1:conv.wino"
-    assert "tuple.3" not in names
+    # the tuple has no op_name: it takes its scoped operand's scope
+    assert names["tuple.3"] == "L1:conv.wino"
     # only the weight prefetched across programs moves outside its layer
     assert scopes.prefetched_scopes(PREFETCH_HLO, names) == {"L19:fc"}
+
+
+# as a TPU v5e compile prints a tile stack built in place: a chain of
+# update fusions with no op_name between two scoped layers, a while body
+# whose ops have none, and an op named outside every layer scope
+BARE_HLO = '''
+HloModule jit_traced, is_scheduled=true
+
+%body (p: (s32[], bf16[6,6])) -> (s32[], bf16[6,6]) {
+  %p = (s32[], bf16[6,6]{1,0}) parameter(0)
+  %gte.1 = bf16[6,6]{1,0} get-tuple-element(%p), index=1
+  %fusion.20 = bf16[6,6]{1,0} fusion(%gte.1), kind=kLoop, calls=%fc.1
+  ROOT %tuple.9 = (s32[], bf16[6,6]{1,0}) tuple(%gte.0, %fusion.20)
+}
+
+ENTRY %main.1 (x.1: f32[8,6,6]) -> f32[8,6] {
+  %x.1 = f32[8,6,6]{2,1,0} parameter(0)
+  %fusion.1 = bf16[8,6,6]{2,1,0} fusion(%x.1), kind=kLoop, calls=%fc.2, metadata={op_name="jit(traced)/L1:conv.wino/slice"}
+  %broadcast.1 = bf16[36,8,6]{2,1,0} broadcast(%constant.5), dimensions={}
+  %constant_dynamic-update-slice_fusion = bf16[36,8,6]{2,1,0} fusion(%broadcast.1, %fusion.1), kind=kLoop, calls=%fc.3
+  %constant_dynamic-update-slice_fusion.1 = bf16[36,8,6]{2,1,0} fusion(%constant_dynamic-update-slice_fusion, %fusion.1), kind=kLoop, calls=%fc.4
+  %while.1 = (s32[], bf16[6,6]{1,0}) while(%tuple.3), condition=%cond, body=%body, metadata={op_name="jit(traced)/L2:conv.spat/while"}
+  %fusion.9 = f32[8,6]{1,0} fusion(%constant_dynamic-update-slice_fusion.1, %while.1), kind=kOutput, calls=%fc.5, metadata={op_name="jit(traced)/L2:conv.spat/dot_general"}
+  ROOT %slice.7 = f32[8,6]{1,0} slice(%fusion.9), slice={[0:8], [0:6]}, metadata={op_name="jit(traced)/slice"}
+}
+'''
+
+
+def test_bare_ops_take_the_scope_of_their_operand_or_reader():
+    names = scopes.hlo_scopes(BARE_HLO)
+    # the update chain: its nearest scoped operand is L1's slice
+    assert names["constant_dynamic-update-slice_fusion"] == "L1:conv.wino"
+    assert names["constant_dynamic-update-slice_fusion.1"] == "L1:conv.wino"
+    # no scoped operand: the nearest reader's, the chain it starts
+    assert names["broadcast.1"] == "L1:conv.wino"
+    # a while body's ops are left to the while that encloses them in time
+    assert not {"p", "gte.1", "fusion.20", "tuple.9"} & set(names)
+    # an op named outside every layer is the program's own, unscoped work
+    assert "slice.7" not in names
+    assert names["while.1"] == names["fusion.9"] == "L2:conv.spat"
 
 
 def test_capture_joins_the_sessions_spans_and_scopes():
@@ -143,58 +199,6 @@ def test_capture_joins_the_sessions_spans_and_scopes():
     assert all(not h[0].startswith("session.") for h in events["host"])
 
 
-def test_layer_costs_sum_to_the_hand_count():
-    layers = _program_layers(VGG16)
-    costs = scopes.layer_costs(vgg16, VGG16, 1, 4, layers)
-    assert len(costs) == 21
-    assert sum(f for _, f, _ in costs) == vgg16.flops_per_image(VGG16)
-    assert round(sum(f for _, f, _ in costs) / 1e9, 2) == 30.94
-    # fc6: 25088 x 4096 weights dominate its bytes; 8 images share them
-    kind, flops, nbytes = scopes.layer_costs(vgg16, VGG16, 8, 4, layers)[18]
-    assert kind == "fc" and flops == 8 * 2 * 25088 * 4096
-    assert nbytes == 4 * (25088 * 4096 + 4096 + 8 * (25088 + 4096))
-    # conv1_1 at int8: weights once, maps per image
-    kind, _, nbytes = scopes.layer_costs(vgg16, VGG16, 8, 1, layers)[0]
-    assert kind == "conv"
-    assert nbytes == 27 * 64 + 64 + 8 * 224 * 224 * (3 + 64)
-
-
-def _model(layers, init_params=vgg16.init_params):
-    return types.SimpleNamespace(layers=lambda sizes: layers,
-                                 init_params=init_params)
-
-
-def test_layer_costs_refuse_what_they_do_not_describe():
-    layers = vgg16.layers(TINY)
-    program = _program_layers(TINY)
-    with pytest.raises(ValueError, match="program's layers"):
-        scopes.layer_costs(vgg16, TINY, 8, 4, program[:-1])
-    with pytest.raises(ValueError, match="program's layers"):
-        scopes.layer_costs(vgg16, TINY, 8, 4,
-                           [(i + 1, k) for i, k in program])
-    # a layer kind, or a form of a known kind, that has no cost here
-    for odd in (("eltwise", 8, 32), ("conv", 32, 3, 4, 7), ("pool", 7, 4)):
-        model = _model(layers[:1] + [odd])
-        with pytest.raises(ValueError, match="no cost"):
-            scopes.layer_costs(model, TINY, 8, 4, [(0, "conv"), (1, odd[0])])
-
-    # a 7x7 stem, told as a 3x3 conv tuple: its weights give it away
-    def seven(key, sizes):
-        params = vgg16.init_params(key, sizes)
-        w, b = params[0]
-        return [(w.repeat(3, 0)[:7].repeat(3, 1)[:, :7], b)] + params[1:]
-    with pytest.raises(ValueError, match="not 3x3"):
-        scopes.layer_costs(_model(layers, seven), TINY, 8, 4, program)
-    # a stride-2 conv: the next layer reads a map it does not write
-    strided = list(layers)
-    _, h, c, k = strided[1]
-    strided[1:] = [("conv", h, c, k)] + [
-        (l[0], l[1] // 2, *l[2:]) if l[0] != "fc" else l
-        for l in strided[2:]]
-    with pytest.raises(ValueError, match="does not read"):
-        scopes.layer_costs(_model(strided), TINY, 8, 4, program)
-
-
 def test_layer_rows_roofline():
     costs = [("conv", 2e9, 1e6), ("fc", 1e6, 8e8)]
     rows = scopes.layer_rows({"L0:conv.spat": 0.04, "L1:fc": 0.02}, 2,
@@ -207,12 +211,17 @@ def test_layer_rows_roofline():
     assert rows[1]["roofline"] == pytest.approx(8.0)
     assert rows[1]["bound"] == "memory"
     assert rows[0]["unmeasured"] is rows[1]["unmeasured"] is None
+    assert not rows[0]["prefetched"] and not rows[1]["prefetched"]
     # prefetched weights, or a share over 100%: not measured, and why
     rows = scopes.layer_rows({"L0:conv.spat": 0.001, "L1:fc": 0.02}, 2,
                              costs, 1e12, 1e12, prefetched={"L1:fc"})
     assert [r["roofline"] for r in rows] == [None, None]
     assert rows[0]["unmeasured"] == "work done in another scope's ops"
     assert rows[1]["unmeasured"] == "weights prefetched across programs"
+    assert [r["prefetched"] for r in rows] == [False, True]
+    # the table leaves the share out; the times it is made of stay
+    assert rows[0]["ms_per_call"] == pytest.approx(0.5)
+    assert rows[0]["least_ms"] == pytest.approx(2.0)
 
 
 @pytest.fixture(scope="module")
@@ -277,8 +286,9 @@ def test_recorded_copies_are_scoped_and_rooflines_bounded(recorded):
               if n.startswith(("copy-start", "copy-done"))]
     assert copies and all(s is not None for _, s in copies)
     r = scopes.reduce_scopes(ev, ids)
-    costs = scopes.layer_costs(vgg16, TINY, round(ev["rows"] / ev["calls"]),
-                               4, _program_layers(TINY))
+    costs = scopes.check_costs(
+        vgg16.layer_costs(TINY, round(ev["rows"] / ev["calls"]), 4),
+        _program_layers(TINY))
     names = scopes.hlo_scopes(ev["hlo"])
     rows = scopes.layer_rows(r["scope_s"], ev["calls"], costs, 197e12,
                              819e9, scopes.prefetched_scopes(ev["hlo"],
@@ -291,3 +301,36 @@ def test_recorded_copies_are_scoped_and_rooflines_bounded(recorded):
     fc = {row["scope"]: row for row in rows if row["scope"].endswith(":fc")}
     assert fc["L20:fc"]["unmeasured"] == "weights prefetched across programs"
     assert 0 < fc["L18:fc"]["roofline"] <= 100.0
+
+
+def test_harness_reduction_of_the_recorded_trace(recorded):
+    """The harness's traced reduction on the recorded trace: ``trace.reduce``'s
+    numbers as they were, with the scopes, the labelled ops and the layer
+    table beside them."""
+    ev, ids = recorded
+    names = scopes.hlo_scopes(ev["hlo"])
+    rows = round(ev["rows"] / ev["calls"])
+    scoped = harness.Scoped(
+        names, scopes.prefetched_scopes(ev["hlo"], names),
+        lambda r: scopes.check_costs(vgg16.layer_costs(TINY, r, 4),
+                                     _program_layers(TINY)))
+    stats = types.SimpleNamespace(batches=ev["calls"],
+                                  dispatched_rows=ev["rows"],
+                                  padded_rows=rows * ev["calls"] - ev["rows"])
+    r = harness.reduce_trace(ev, ids, scoped, stats, 197e12, 819e9)
+    base = tracing.reduce(ev, ids)
+    for k in ("window_s", "busy_s", "module_s", "idle_gaps"):
+        assert r[k] == base[k], k
+    assert r["scoped_share"] >= scopes.MIN_SCOPED
+    assert r["scope_s"] and r["scope_s"] == scopes.reduce_scopes(
+        ev, ids)["scope_s"]
+    assert [t for _, t in r["device_ops"]] == [t for _, t in
+                                               base["device_ops"]]
+    assert all(" [L" in name for name, _ in r["device_ops"])
+    assert [row["scope"] for row in r["layers"]] == list(r["scope_s"])
+    # without scopes: trace.reduce's numbers, and no layer reads
+    bare = harness.reduce_trace(dict(ev, devices={
+        k: {kk: vv for kk, vv in v.items() if kk != "op_scopes"}
+        for k, v in ev["devices"].items()}), ids, None, stats, 197e12, 819e9)
+    assert bare["busy_s"] == base["busy_s"]
+    assert bare["scope_s"] is None and bare["layers"] is None
